@@ -39,6 +39,7 @@ import numpy as np
 import repro.data as data
 from repro import obs
 from repro.core import comtune
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import cnn
 from repro.optim import AdamConfig, adam_update, init_adam
 
@@ -247,6 +248,7 @@ def trainer_bench(smoke: bool, arch: str = "qwen1.5-0.5b") -> dict:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--out", default="BENCH_comtune.json")

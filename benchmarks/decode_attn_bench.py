@@ -39,6 +39,7 @@ import numpy as np
 from repro import obs
 from repro.configs import ARCHITECTURES
 from repro.kernels.decode_attention import decode_attention, decode_block_kv
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import cache as cache_lib, lm
 from repro.models.attention import _naive_attn, _read_cache
 from repro.serve import ContinuousEngine, PoolConfig
@@ -193,6 +194,7 @@ def engine_bench(tokens: int = 12, n_requests: int = 8) -> dict:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--max-seq", type=int, default=1024)
     ap.add_argument("--valids", default="16,64,128,256,512,1024",

@@ -23,6 +23,7 @@ import numpy as np
 from repro import obs
 from repro.configs import ARCHITECTURES, get_config
 from repro.launch.serve import generate_reference
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import cache as cache_lib, lm
 from repro.obs.stats import latency_summary
 from repro.serve import DecodeEngine
@@ -111,6 +112,7 @@ def run_bench(
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=sorted(ARCHITECTURES))
     ap.add_argument("--batch", type=int, default=4)

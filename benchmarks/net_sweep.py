@@ -27,6 +27,7 @@ import zlib
 import numpy as np
 
 from repro.core.link import ChannelConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.net import (
     FECSpec,
     ARQProtocol,
@@ -142,6 +143,7 @@ def sweep(loss_rates, n_eval: int, train_steps: int):
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--loss-rates", type=float, nargs="+",
                     default=[0.1, 0.3, 0.6])

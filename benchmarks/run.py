@@ -16,6 +16,8 @@ import time
 
 from benchmarks import paper_figs, roofline_report
 
+from repro.launch.compile_cache import setup_compile_cache
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 
 BENCHES = [
@@ -30,6 +32,7 @@ BENCHES = [
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="comma-separated bench names")
     ap.add_argument("--skip-roofline", action="store_true")

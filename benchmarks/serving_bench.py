@@ -83,6 +83,7 @@ from repro import obs
 from repro.analysis.guards import no_recompile
 from repro.configs import ARCHITECTURES, get_config
 from repro.core import link as link_lib
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import cache as cache_lib, lm
 from repro.net.chaos import (
     ChaosSchedule,
@@ -844,6 +845,7 @@ def run_sharded_bench(
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=sorted(ARCHITECTURES))
     ap.add_argument("--clients", type=int, default=24)

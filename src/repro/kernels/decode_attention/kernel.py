@@ -1,51 +1,46 @@
-"""Flash-decode forward kernel (Pallas): length-masked online-softmax
+"""Flash-decode forward kernels (Pallas): length-masked online-softmax
 attention for the s == 1 decode step, with inline int8 dequantization.
 
-Shapes follow the decode cache's native layout so no transpose/copy of the
-cache is ever materialized:
+The model's cache layout is untouched; each wrapper views it lane-dense:
 
 * q        — (B, KV, G, hd)   one query token, GQA-grouped
-* k / v    — (B, C, KV, hd)   rotating cache buffer (int8 codes or bf16)
+* k / v    — (B, C, KV, hd)   rotating cache buffer (int8 codes or bf16),
+                              read as (B, C, KV*hd) — a free reshape
 * k/v scale— (B, C, KV)       per-(pos, head) bf16 absmax scales (int8 only)
 * n_valid  — (B, 1) int32     count of live cache slots for this request
 
-Grid: (B, KV) — one grid step per (request, kv-head).  The kernel holds
-the (G, hd) query tile plus the (C, hd) K/V panels for that head
-(BlockSpec-delivered, strided view of the native (B, C, KV, hd) buffer)
-and walks KV blocks with a ``fori_loop`` whose upper bound is
-``ceil(n_valid / block_kv)`` — blocks past the valid prefix are never
-*computed on or dequantized*, which turns the decode step's FLOPs and
-dequant work from O(max_seq) into O(valid).  Caveat on *memory* traffic:
-with this portable BlockSpec a compiled TPU run still DMAs the full
-(C, hd) panel into VMEM before the body runs, so the O(valid) HBM-bytes
-claim currently holds for the jnp fallback (``ref.py`` — XLA dynamic
-slices read only the walked blocks), while TPU gets the compute/dequant
-saving.  ``paged_flash_decode_kernel`` below closes that gap for the
-block-pool layout: ``n_valid`` and the block table ride as
-scalar-prefetch (SMEM) operands of a ``PrefetchScalarGridSpec``, so the
-index map resolves physical blocks *before* each DMA fires and only
-walked blocks ever move — O(valid) bytes on TPU too.
-Rotating sliding-window caches need no extra handling: writes
-land at ``index % C`` (``models.attention._write_decode``), so the live
-slots are always the contiguous prefix ``[0, min(index + 1, C))`` — once
-the window wraps, ``n_valid == C`` and the masked walk degenerates to the
-full (bounded) window.  Cached keys carry RoPE from write time and softmax
-is permutation-invariant over slots, so slot order never matters.
+TPU tiles the last two dims of every VMEM block by (8, 128), so a block
+that picks one KV head out of ``(C, KV, hd)`` is refused.  Here the
+minor dim is the flattened ``KV*hd`` lane axis, cut into *head blocks*
+of ``hpb`` whole heads whose lanes fill a multiple of 128 (hd 64 -> two
+heads per 128 lanes; hd 128 -> one), or all heads when no such cut
+exists (then the block spans the whole array dim, which is always legal).
+One head block is attended with plain 2-D matmuls: the wrapper expands
+the query into a block-diagonal ``(hpb*G, hpb*hd)`` tile whose row of
+head j is zero outside head j's lanes, so ``q @ k.T`` sums each row over
+its own head only, and ``p @ v`` leaves head j's output in head j's lanes
+(the other lanes of that row are discarded by the wrapper).  int8 scales
+are spread onto the lanes by a ``(bkv, KV) @ (KV, lanes)`` 0/1 selector
+matmul at full precision, which copies each scale exactly.
 
-Inline dequantization: int8 codes are loaded per block and scaled in
-VMEM/registers (``codes_f32 * scale_f32``), so the quantized cache is
-never expanded to bf16 in HBM — the full-cache ``_read_cache`` dequant
-this kernel replaces was the dominant decode-step HBM traffic.
+Contiguous kernel: grid (B, head blocks); the body walks KV blocks with a
+``fori_loop`` whose upper bound is ``ceil(n_valid / block_kv)`` (``n_valid``
+in SMEM) — blocks past the valid prefix are never computed on or
+dequantized.  HBM traffic: each grid step's BlockSpec DMAs the whole
+``(C, hpb*hd)`` K and V panels of its head block, so the contiguous kernel
+reads the full cache (O(max_seq) bytes); the scale block ``(C, KV)`` keeps
+its index across a request's head blocks and is fetched once per request.
+Paged kernel: reads only the walked blocks (see below).
 
-The kernel is vmap-able (the slot-pool engine vmaps it over the slot axis
-with a per-slot ``n_valid``); ``ref.py`` mirrors this file's f32
-arithmetic op for op, so the pure-jnp fallback agrees with the
-interpret-mode kernel to float-ulp level (tests pin ~2e-6; XLA fusion
-reassociation is the only difference).
+Rotating sliding-window caches need no extra handling: writes land at
+``index % C`` (``models.attention._write_decode``), so the live slots are
+always the contiguous prefix ``[0, min(index + 1, C))``.  Cached keys carry
+RoPE from write time and softmax is permutation-invariant over slots.
 
-``n_valid`` rides as a (1, 1) int32 VMEM block per grid step; the
-portable spec keeps one code path for interpret/Triton/Mosaic (see the
-memory-traffic caveat above for what a TPU SMEM prefetch would add).
+The contiguous kernel is vmap-able (the slot-pool engine vmaps it over the
+slot axis with a per-slot ``n_valid``); ``ref.py`` runs the same f32
+arithmetic per head, so the jnp fallback agrees with the interpret-mode
+kernel to float-ulp level (XLA summation order is the only difference).
 """
 
 from __future__ import annotations
@@ -61,55 +56,109 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.runtime import pallas_interpret
 
 NEG_INF = -1.0e30
+LANES = 128
 
 
-def _make_kernel(*, block_kv: int, softcap: float, quantized: bool):
+def _heads_per_block(kvh: int, hd: int) -> int:
+    """Fewest whole heads whose lanes fill a multiple of 128; all heads
+    when no divisor of ``kvh`` does."""
+    for hpb in range(1, kvh + 1):
+        if kvh % hpb == 0 and (hpb * hd) % LANES == 0:
+            return hpb
+    return kvh
+
+
+def _expand_q(q: jax.Array, hpb: int) -> jax.Array:
+    """(B, KV, G, hd) -> block-diagonal (B, KV/hpb, hpb*G, hpb*hd)."""
+    b, kvh, g, hd = q.shape
+    qr = q.reshape(b, kvh // hpb, hpb, g, 1, hd)
+    diag = jnp.eye(hpb, dtype=bool).reshape(1, 1, hpb, 1, hpb, 1)
+    qx = jnp.where(diag, qr, jnp.zeros((), q.dtype))
+    return qx.reshape(b, kvh // hpb, hpb * g, hpb * hd)
+
+
+def _collapse_out(o: jax.Array, hpb: int, g: int, hd: int) -> jax.Array:
+    """Inverse of :func:`_expand_q`: keep head j's lanes of head j's rows."""
+    b, nhb = o.shape[:2]
+    o6 = o.reshape(b, nhb, hpb, g, hpb, hd)
+    diag = jnp.diagonal(o6, axis1=2, axis2=4)                # (b, nhb, g, hd, hpb)
+    return jnp.moveaxis(diag, -1, 2).reshape(b, nhb * hpb, g, hd)
+
+
+def _lane_selector(kvh: int, hpb: int, hd: int, hb) -> jax.Array:
+    """(KV, hpb*hd) f32: 1 where lane belongs to head h of head block hb."""
+    shape = (kvh, hpb * hd)
+    local = jax.lax.broadcasted_iota(jnp.int32, shape, 0) - hb * hpb
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return ((lane >= local * hd) & (lane < local * hd + hd)).astype(jnp.float32)
+
+
+def _dequant(codes, scales, sel):
+    """int8 codes (bkv, lanes) * per-(pos, head) scales (bkv, KV) spread
+    onto the lanes by the 0/1 selector — exact at HIGHEST precision."""
+    lanes = jax.lax.dot_general(
+        scales.astype(jnp.float32), sel, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    return codes.astype(jnp.float32) * lanes
+
+
+def _attend(q, k, v, pos0, n_valid, carry, *, softcap: float, scale):
+    """One online-softmax update of rows q (R, L) over k/v (bkv, L)."""
+    acc, m, l = carry                                        # (R,L) (R,1) (R,1)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                                                # (R, bkv)
+    if softcap > 0.0:
+        s = jnp.tanh(s / softcap) * softcap
+    k_pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    msk = k_pos < n_valid
+    s = jnp.where(msk, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(msk, p, 0.0)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return acc * corr + pv, m_new, l_new
+
+
+def _make_kernel(*, block_kv, softcap, quantized, kvh, hpb, hd):
     def kernel(*refs):
         if quantized:
-            q_ref, k_ref, v_ref, ks_ref, vs_ref, n_ref, o_ref = refs
+            n_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref = refs
         else:
-            q_ref, k_ref, v_ref, n_ref, o_ref = refs
-            ks_ref = vs_ref = None
-        q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-        g, hd = q.shape
+            n_ref, q_ref, k_ref, v_ref, o_ref = refs
+        q = q_ref[0, 0].astype(jnp.float32)                  # (R, L)
         scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-        n_valid = n_ref[0, 0]
+        n_valid = n_ref[0, 0, 0]
         n_blocks = (n_valid + block_kv - 1) // block_kv
+        if quantized:
+            sel = _lane_selector(kvh, hpb, hd, pl.program_id(1))
 
         def body(kj, carry):
-            acc, m, l = carry
-            sl = pl.dslice(kj * block_kv, block_kv)
-            k = k_ref[0, sl, 0, :].astype(jnp.float32)       # (bkv, hd)
-            v = v_ref[0, sl, 0, :].astype(jnp.float32)
+            start = pl.multiple_of(kj * block_kv, block_kv)
+            sl = pl.ds(start, block_kv)
             if quantized:
-                k = k * ks_ref[0, sl, 0].astype(jnp.float32)[:, None]
-                v = v * vs_ref[0, sl, 0].astype(jnp.float32)[:, None]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                        # (G, bkv)
-            if softcap > 0.0:
-                s = jnp.tanh(s / softcap) * softcap
-            k_pos = kj * block_kv + jax.lax.iota(jnp.int32, block_kv)
-            msk = (k_pos < n_valid)[None, :]
-            s = jnp.where(msk, s, NEG_INF)
-            s_max = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m, s_max)
-            p = jnp.exp(s - m_new[:, None])
-            p = jnp.where(msk, p, 0.0)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return acc * corr[:, None] + pv, m_new, l_new
+                k = _dequant(k_ref[0, sl, :], ks_ref[0, sl, :], sel)
+                v = _dequant(v_ref[0, sl, :], vs_ref[0, sl, :], sel)
+            else:
+                k = k_ref[0, sl, :].astype(jnp.float32)      # (bkv, L)
+                v = v_ref[0, sl, :].astype(jnp.float32)
+            return _attend(q, k, v, start, n_valid, carry,
+                           softcap=softcap, scale=scale)
 
-        acc0 = jnp.zeros((g, hd), jnp.float32)
-        m0 = jnp.full((g,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((g,), jnp.float32)
-        acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+        r, lw = q.shape
+        init = (jnp.zeros((r, lw), jnp.float32),
+                jnp.full((r, 1), NEG_INF, jnp.float32),
+                jnp.zeros((r, 1), jnp.float32))
+        acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
+        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
     return kernel
 
@@ -133,28 +182,30 @@ def flash_decode_kernel(
     c = k.shape[1]
     assert c % block_kv == 0, (c, block_kv)
     quantized = k_scale is not None
+    hpb = _heads_per_block(kvh, hd)
+    nhb, r, lw = kvh // hpb, hpb * g, hpb * hd
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda i, h: (i, h, 0, 0)),
-        pl.BlockSpec((1, c, 1, hd), lambda i, h: (i, 0, h, 0)),
-        pl.BlockSpec((1, c, 1, hd), lambda i, h: (i, 0, h, 0)),
+        pl.BlockSpec((1, 1, 1), lambda i, hb: (i, 0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, r, lw), lambda i, hb: (i, hb, 0, 0)),
+        pl.BlockSpec((1, c, lw), lambda i, hb: (i, 0, hb)),
+        pl.BlockSpec((1, c, lw), lambda i, hb: (i, 0, hb)),
     ]
-    args = [q, k, v]
+    args = [jnp.asarray(n_valid, jnp.int32).reshape(b, 1, 1), _expand_q(q, hpb),
+            k.reshape(b, c, kvh * hd), v.reshape(b, c, kvh * hd)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, c, 1), lambda i, h: (i, 0, h)),
-            pl.BlockSpec((1, c, 1), lambda i, h: (i, 0, h)),
-        ]
+        in_specs += [pl.BlockSpec((1, c, kvh), lambda i, hb: (i, 0, 0))] * 2
         args += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, 1), lambda i, h: (i, 0)))
-    args.append(n_valid)
-    return pl.pallas_call(
-        _make_kernel(block_kv=block_kv, softcap=softcap, quantized=quantized),
-        grid=(b, kvh),
+    out = pl.pallas_call(
+        _make_kernel(block_kv=block_kv, softcap=softcap, quantized=quantized,
+                     kvh=kvh, hpb=hpb, hd=hd),
+        grid=(b, nhb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, h: (i, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, r, lw), lambda i, hb: (i, hb, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
         interpret=pallas_interpret(interpret),
     )(*args)
+    return _collapse_out(out, hpb, g, hd)
 
 
 # ---------------------------------------------------------------------------
@@ -163,16 +214,21 @@ def flash_decode_kernel(
 #
 # Same online-softmax arithmetic, different iteration structure: the KV
 # walk moves from a fori_loop inside one grid step to the (sequential,
-# minor) third grid dimension, because with a PrefetchScalarGridSpec it is
-# the *index map* — evaluated from SMEM-resident scalars before the DMA —
-# that picks which physical (block_size, hd) block to deliver.  Softmax
-# state (acc, m, l) persists across the j steps in VMEM scratch;
-# ``pl.when`` guards init (j == 0), the masked walk (j * block_size <
-# n_valid — blocks past the valid prefix are neither computed on nor, on
-# TPU, fetched), and the final normalize/write (last j).
+# minor) grid dimension j, because with a PrefetchScalarGridSpec it is the
+# *index map* — evaluated from SMEM-resident scalars before the DMA — that
+# picks which physical block to deliver.  Each step delivers one whole
+# pool block across all heads, ``(block_size, KV*hd)``, and walks the head
+# blocks with static 128-aligned lane slices; softmax state for every head
+# block persists across j in VMEM scratch.  The index map clamps j to the
+# last valid block, so steps past the valid prefix repeat the previous
+# block index and Pallas issues no DMA for them: the paged kernel reads
+# O(valid) bytes.  ``pl.when`` guards init (j == 0), the masked walk
+# (j * block_size < n_valid) and the final normalize/write (last j).
 
 
-def _make_paged_kernel(*, block_size: int, softcap: float, quantized: bool):
+def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
+    nhb, lw = kvh // hpb, hpb * hd
+
     def kernel(*refs):
         if quantized:
             (nv_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
@@ -180,10 +236,9 @@ def _make_paged_kernel(*, block_size: int, softcap: float, quantized: bool):
         else:
             (nv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
              acc_ref, m_ref, l_ref) = refs
-            ks_ref = vs_ref = None
         del bt_ref  # consumed by the index maps, not the body
         i = pl.program_id(0)
-        j = pl.program_id(2)
+        j = pl.program_id(1)
         n_valid = nv_ref[i]
 
         @pl.when(j == 0)
@@ -194,42 +249,30 @@ def _make_paged_kernel(*, block_size: int, softcap: float, quantized: bool):
 
         @pl.when(j * block_size < n_valid)
         def _block():
-            q = q_ref[0, 0].astype(jnp.float32)              # (G, hd)
-            scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-            k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bs, hd)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-            if quantized:
-                k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-                v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                        # (G, bs)
-            if softcap > 0.0:
-                s = jnp.tanh(s / softcap) * softcap
-            k_pos = j * block_size + jax.lax.iota(jnp.int32, block_size)
-            msk = (k_pos < n_valid)[None, :]
-            s = jnp.where(msk, s, NEG_INF)
-            m = m_ref[:, 0]
-            l = l_ref[:, 0]
-            s_max = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m, s_max)
-            p = jnp.exp(s - m_new[:, None])
-            p = jnp.where(msk, p, 0.0)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-            m_ref[...] = m_new[:, None]
-            l_ref[...] = l_new[:, None]
+            scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+            for hb in range(nhb):
+                lanes = slice(hb * lw, (hb + 1) * lw)
+                if quantized:
+                    sel = _lane_selector(kvh, hpb, hd, hb)
+                    k = _dequant(k_ref[0, :, lanes], ks_ref[0], sel)
+                    v = _dequant(v_ref[0, :, lanes], vs_ref[0], sel)
+                else:
+                    k = k_ref[0, :, lanes].astype(jnp.float32)
+                    v = v_ref[0, :, lanes].astype(jnp.float32)
+                acc, m, l = _attend(
+                    q_ref[0, hb].astype(jnp.float32), k, v,
+                    j * block_size, n_valid,
+                    (acc_ref[hb], m_ref[hb], l_ref[hb]),
+                    softcap=softcap, scale=scale,
+                )
+                acc_ref[hb] = acc
+                m_ref[hb] = m
+                l_ref[hb] = l
 
-        @pl.when(j == pl.num_programs(2) - 1)
+        @pl.when(j == pl.num_programs(1) - 1)
         def _finish():
-            o_ref[0, 0] = (
-                acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-20)[:, None]
+            o_ref[0] = (
+                acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
             ).astype(o_ref.dtype)
 
     return kernel
@@ -252,43 +295,46 @@ def paged_flash_decode_kernel(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, kvh, g, hd = q.shape
-    bs = k.shape[1]
+    n_phys, bs = k.shape[:2]
     assert bs == block_size, (bs, block_size)
     j_l = block_table.shape[1]
     quantized = k_scale is not None
-    kv_map = lambda i, h, j, nv, bt: (bt[i, j], 0, h, 0)
-    sc_map = lambda i, h, j, nv, bt: (bt[i, j], 0, h)
+    hpb = _heads_per_block(kvh, hd)
+    nhb, r, lw = kvh // hpb, hpb * g, hpb * hd
+
+    def block_at(i, j, nv, bt):
+        last = jnp.maximum(nv[i] - 1, 0) // block_size
+        return bt[i, jnp.minimum(j, last)]
+
+    kv_map = lambda i, j, nv, bt: (block_at(i, j, nv, bt), 0, 0)
+    per_req = lambda i, j, nv, bt: (i, 0, 0, 0)
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda i, h, j, nv, bt: (i, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
-        pl.BlockSpec((1, bs, 1, hd), kv_map),
+        pl.BlockSpec((1, nhb, r, lw), per_req),
+        pl.BlockSpec((1, bs, kvh * hd), kv_map),
+        pl.BlockSpec((1, bs, kvh * hd), kv_map),
     ]
-    args = [q, k, v]
+    args = [_expand_q(q, hpb), k.reshape(n_phys, bs, kvh * hd),
+            v.reshape(n_phys, bs, kvh * hd)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bs, 1), sc_map),
-            pl.BlockSpec((1, bs, 1), sc_map),
-        ]
+        in_specs += [pl.BlockSpec((1, bs, kvh), kv_map)] * 2
         args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, j_l),
+        grid=(b, j_l),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, g, hd), lambda i, h, j, nv, bt: (i, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, nhb, r, lw), per_req),
         scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),                # acc
-            pltpu.VMEM((g, 1), jnp.float32),                 # m
-            pltpu.VMEM((g, 1), jnp.float32),                 # l
+            pltpu.VMEM((nhb, r, lw), jnp.float32),           # acc
+            pltpu.VMEM((nhb, r, 1), jnp.float32),            # m
+            pltpu.VMEM((nhb, r, 1), jnp.float32),            # l
         ],
     )
-    return pl.pallas_call(
-        _make_paged_kernel(
-            block_size=block_size, softcap=softcap, quantized=quantized,
-        ),
+    out = pl.pallas_call(
+        _make_paged_kernel(block_size=block_size, softcap=softcap,
+                           quantized=quantized, kvh=kvh, hpb=hpb, hd=hd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
         interpret=pallas_interpret(interpret),
     )(jnp.asarray(n_valid, jnp.int32), jnp.asarray(block_table, jnp.int32),
       *args)
+    return _collapse_out(out, hpb, g, hd)

@@ -1,11 +1,12 @@
 """Pure-jnp length-masked flash-decode fallback — the CPU production path.
 
-This is NOT a naive oracle: it mirrors ``kernel.py`` operation for
-operation (same f32 dequant, same ``lax.dot_general`` dimension numbers
-and ``preferred_element_type``, same mask/where order, same online-softmax
-update expressions, same ``fori_loop`` bound ``ceil(n_valid / block_kv)``)
-so CPU CI exercises the same arithmetic recipe the accelerator kernel
-runs, at the kernel's O(valid) cost: the traced loop bound lowers to a
+This is NOT a naive oracle: it runs ``kernel.py``'s arithmetic per head
+(same f32 dequant, same ``preferred_element_type``, same mask/where
+order, same online-softmax update expressions, same ``fori_loop`` bound
+``ceil(n_valid / block_kv)``) — the kernel batches a head block into one
+block-diagonal matmul whose extra terms are exact zeros — so CPU CI
+exercises the same arithmetic recipe the accelerator kernel runs, at the
+kernel's O(valid) cost: the traced loop bound lowers to a
 ``while_loop``, so blocks past the valid prefix are never read or
 dequantized.  Against the interpret-mode kernel the outputs agree to
 float-ulp level (~2e-6 in f32, pinned by tests) — the only residual

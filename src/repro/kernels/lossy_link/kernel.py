@@ -13,8 +13,9 @@ keeping RNG outside makes interpret-mode validation bit-exact against the
 jnp oracle.
 
 Tiling: (block_t, block_d) VMEM tiles over the (tokens, d_model) activation;
-the per-feature scale factors are (block_d,) tiles broadcast down the token
-axis.  block_d is a multiple of 128 (VPU lane width).
+the per-feature scale factors ride as (1, D) rows in (1, block_d) tiles
+broadcast down the token axis (TPU has no 1-D VMEM block).  block_d is a
+multiple of 128 (VPU lane width).
 """
 
 from __future__ import annotations
@@ -34,8 +35,8 @@ def _egress_kernel(
 ):
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
-    s_min = smin_ref[...].astype(jnp.float32)[None, :]
-    s_max = smax_ref[...].astype(jnp.float32)[None, :]
+    s_min = smin_ref[...].astype(jnp.float32)                 # (1, block_d)
+    s_max = smax_ref[...].astype(jnp.float32)
 
     levels = jnp.float32(2**bits - 1)
     rng = jnp.maximum(s_max - s_min, 1e-8)
@@ -61,28 +62,36 @@ def _burst_mask_kernel(
 ):
     """One block of independent Gilbert–Elliott chains.
 
-    Rows are independent channel realizations (one per message in the
-    serving batch); columns are packets in sequence.  The hidden Good/Bad
-    state is carried down the packet axis by a ``fori_loop`` writing one
-    lane-column per step — the chain is inherently sequential in time, but
-    the whole batch of rows advances in lockstep on the VPU, so the Markov
-    process never leaves the device on the jit-compiled serving path.
+    Lanes are independent channel realizations (one per message in the
+    serving batch); sublane rows are packets in sequence.  The hidden
+    Good/Bad state is carried down the packet axis by a ``fori_loop``
+    reading and writing one row per step — the chain is inherently
+    sequential in time, but the whole block of chains advances in lockstep
+    on the VPU, so the Markov process never leaves the device.  Packets sit
+    on the sublane axis because TPU loads a dynamic row offset, but not a
+    dynamic single-lane column.
     """
     pi_b = p_gb / max(p_gb + p_bg, 1e-12)
-    bad = (uinit_ref[...] < jnp.float32(pi_b)).reshape(-1, 1)  # (block_r, 1)
-    # Loop only the true packet count: the chain is inherently sequential,
-    # so stepping the lane-padding columns (discarded by the wrapper's
-    # out[:r, :n] slice) would cost real wall-clock.
-    n = n_valid
+    # The state rides the loop as int32 0/1 and every bool is consumed by a
+    # select: Mosaic can neither carry nor convert i1 vectors.
+    one, zero = jnp.int32(1), jnp.int32(0)
+    bad0 = jnp.where(uinit_ref[...] < jnp.float32(pi_b), one, zero)  # (1, block_r)
 
     def body(t, bad):
-        ul = uloss_ref[:, pl.ds(t, 1)]                         # (block_r, 1)
-        ut = utr_ref[:, pl.ds(t, 1)]
+        bad = bad != 0
+        ul = uloss_ref[pl.ds(t, 1), :]                         # (1, block_r)
+        ut = utr_ref[pl.ds(t, 1), :]
         p = jnp.where(bad, jnp.float32(loss_bad), jnp.float32(loss_good))
-        o_ref[:, pl.ds(t, 1)] = (ul >= p).astype(o_ref.dtype)
-        return jnp.where(bad, ut >= jnp.float32(p_bg), ut < jnp.float32(p_gb))
+        o_ref[pl.ds(t, 1), :] = jnp.where(
+            ul >= p, jnp.float32(1.0), jnp.float32(0.0)
+        ).astype(o_ref.dtype)
+        stay_bad = jnp.where(ut >= jnp.float32(p_bg), one, zero)
+        go_bad = jnp.where(ut < jnp.float32(p_gb), one, zero)
+        return jnp.where(bad, stay_bad, go_bad)
 
-    jax.lax.fori_loop(0, n, body, bad)
+    # Loop only the true packet count: stepping the padding rows (discarded
+    # by the wrapper's slice) would cost real wall-clock.
+    jax.lax.fori_loop(0, n_valid, body, bad0)
 
 
 @functools.partial(
@@ -100,20 +109,22 @@ def burst_mask_kernel(
     p_bg: float,
     loss_good: float,
     loss_bad: float,
-    block_r: int = 8,
+    block_r: int = 128,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(R, N) float32 Gilbert–Elliott packet keep-masks, bit-exact against
-    ``ref.burst_mask_ref`` for identical uniforms."""
+    ``ref.burst_mask_ref`` for identical uniforms.  Runs on the transposed
+    (packets, chains) layout; ``block_r`` chains per lane block."""
     r, n = u_loss.shape
-    br = min(block_r, r)
-    pad_r = (-r) % br
-    pad_n = (-n) % 128          # lane-align the packet axis
-    if pad_r or pad_n:
-        u_init = jnp.pad(u_init, (0, pad_r), constant_values=1.0)
-        u_loss = jnp.pad(u_loss, ((0, pad_r), (0, pad_n)), constant_values=1.0)
-        u_tr = jnp.pad(u_tr, ((0, pad_r), (0, pad_n)), constant_values=1.0)
-    rp, np_ = u_loss.shape
+    br = block_r if r > block_r else -(-r // 128) * 128   # lane-dense block
+    rp = -(-r // br) * br
+    np_ = -(-n // 8) * 8                                  # sublane-align packets
+    pad = lambda a, fill: jnp.pad(
+        a.astype(jnp.float32).T, ((0, np_ - n), (0, rp - r)),
+        constant_values=fill,
+    )
+    u_init = jnp.pad(u_init.astype(jnp.float32), (0, rp - r),
+                     constant_values=1.0).reshape(1, rp)
     out = pl.pallas_call(
         functools.partial(
             _burst_mask_kernel,
@@ -122,16 +133,15 @@ def burst_mask_kernel(
         ),
         grid=(rp // br,),
         in_specs=[
-            pl.BlockSpec((br,), lambda i: (i,)),
-            pl.BlockSpec((br, np_), lambda i: (i, 0)),
-            pl.BlockSpec((br, np_), lambda i: (i, 0)),
+            pl.BlockSpec((1, br), lambda i: (0, i)),
+            pl.BlockSpec((np_, br), lambda i: (0, i)),
+            pl.BlockSpec((np_, br), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((br, np_), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rp, np_), jnp.float32),
+        out_specs=pl.BlockSpec((np_, br), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((np_, rp), jnp.float32),
         interpret=pallas_interpret(interpret),
-    )(u_init.astype(jnp.float32), u_loss.astype(jnp.float32),
-      u_tr.astype(jnp.float32))
-    return out[:r, :n]
+    )(u_init, pad(u_loss, 1.0), pad(u_tr, 1.0))
+    return out[:n, :r].T
 
 
 @functools.partial(
@@ -166,11 +176,11 @@ def lossy_link_egress_kernel(
         in_specs=[
             pl.BlockSpec((bt, bd), lambda i, j: (i, j)),
             pl.BlockSpec((bt, bd), lambda i, j: (i, j)),
-            pl.BlockSpec((bd,), lambda i, j: (j,)),
-            pl.BlockSpec((bd,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bd), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bd), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bt, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=pallas_interpret(interpret),
-    )(x, u, s_min, s_max)
+    )(x, u, s_min.reshape(1, -1), s_max.reshape(1, -1))
     return out[:t, :d]

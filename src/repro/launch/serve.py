@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs import ARCHITECTURES, get_config
 from repro.core import ChannelConfig, comtune
 from repro.core.compression import Compressor, PCASpec, QuantSpec
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import cache as cache_lib, lm
 from repro.obs import get_logger
@@ -213,6 +214,7 @@ def _accounting_compressor(cfg) -> Compressor:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHITECTURES), required=True)
     ap.add_argument("--batch", type=int, default=4)

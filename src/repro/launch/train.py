@@ -44,6 +44,7 @@ from repro.configs import ARCHITECTURES, get_config
 from repro.configs.base import ShapeConfig
 from repro.data import lm_batch_iterator, make_lm_dataset
 from repro import obs
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (
     build_sharded_epoch,
@@ -424,6 +425,7 @@ def _parse_fec(s: Optional[str]):
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHITECTURES), required=True)
     ap.add_argument("--steps", type=int, default=200)

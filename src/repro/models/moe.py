@@ -31,33 +31,6 @@ from repro.configs.base import ModelConfig
 from repro.models.common import Params, activation, dense_init, split_keys
 from repro.models.mlp import init_mlp, mlp_forward
 
-# Version shim: jax.shard_map(check_vma=) is the current API; older
-# releases spell it jax.shard_map(check_rep=) or live under
-# jax.experimental.shard_map.  Gate on the actual signature, not presence.
-if hasattr(jax, "shard_map"):
-    import inspect
-
-    _SM_KW = (
-        "check_vma"
-        if "check_vma" in inspect.signature(jax.shard_map).parameters
-        else "check_rep"
-    )
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **{_SM_KW: False},
-        )
-else:                                             # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return _exp_shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
 def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     d, e, f = cfg.d_model, cfg.num_experts, cfg.moe_dff or cfg.d_ff
     ks = split_keys(key, 6)
@@ -281,11 +254,12 @@ def moe_forward_shard_map(
         out = jax.lax.psum(out, model_ax)
         return out.reshape(bl, sl, d), aux
 
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(p_specs, x_spec),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )(p_routed, x)
 
     # dense side paths (plain GSPMD tensor parallelism)

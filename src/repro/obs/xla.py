@@ -20,10 +20,7 @@ _LOCK = threading.Lock()
 _SUBSCRIBED = False
 _BUILDS = 0
 
-try:  # the canonical event name lives in a private module; pin a fallback
-    from jax._src.dispatch import BACKEND_COMPILE_EVENT  # type: ignore
-except Exception:  # pragma: no cover - future jax versions
-    BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
 
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:

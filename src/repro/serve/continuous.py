@@ -69,6 +69,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro import obs
 from repro.configs.base import ModelConfig
@@ -365,8 +366,8 @@ class ContinuousEngine:
     # -- static program construction --------------------------------------
 
     def _dev_ctx(self):
-        """Context placing array creation AND AOT lowering on this
-        engine's device (no-op for the default single-device engine)."""
+        """Context placing array creation on this engine's device (no-op
+        for the default single-device engine)."""
         if self.device is None:
             return contextlib.nullcontext()
         return jax.default_device(self.device)
@@ -385,19 +386,22 @@ class ContinuousEngine:
 
     def _aot(self, fn, donate: Tuple[int, ...], avals: Tuple) -> Any:
         """jit -> lower -> compile; returns the Compiled executable and
-        bumps the engine-wide compile/trace accounting.  Lowering runs
-        under ``_dev_ctx`` so a device-pinned engine's executables target
-        its own device (AOT avals carry no placement of their own)."""
+        bumps the engine-wide compile/trace accounting.  A device-pinned
+        engine gives every aval a ``SingleDeviceSharding`` on its device,
+        so its executables target that device and no other."""
 
         def traced(*args):
             self.traces += 1     # Python side effect: fires at trace time
             return fn(*args)
 
+        if self.device is not None:
+            here = SingleDeviceSharding(self.device)
+            avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=here),
+                avals,
+            )
         t0 = time.perf_counter()
-        with self._dev_ctx():
-            compiled = jax.jit(
-                traced, donate_argnums=donate
-            ).lower(*avals).compile()
+        compiled = jax.jit(traced, donate_argnums=donate).lower(*avals).compile()
         self.compile_s += time.perf_counter() - t0
         self.compiles += 1
         return compiled
@@ -762,6 +766,25 @@ class ContinuousEngine:
     @property
     def num_buckets(self) -> int:
         return len(self._prefill_fns)
+
+    @property
+    def decode_executable(self):
+        """The fused decode step's ``jax.stages.Compiled`` (None until the
+        first request is admitted)."""
+        return self._decode_fn
+
+    def devices_in_use(self) -> set:
+        """Devices that hold this engine's pool state or that its compiled
+        programs take their inputs on."""
+        devs = {
+            d for leaf in jax.tree_util.tree_leaves(self._state)
+            for d in leaf.devices()
+        }
+        for fn in (self._decode_fn, *self._prefill_fns.values()):
+            if fn is not None:
+                for s in jax.tree_util.tree_leaves(fn.input_shardings):
+                    devs |= s.device_set
+        return devs
 
     @property
     def active(self) -> int:

@@ -491,17 +491,24 @@ def sharded_engine(
     num_shards: int = 0,
 ) -> ShardedEngine:
     """Router per (cfg, pool, num_shards) — pools and compiled programs
-    survive across callers.  ``num_shards=0`` spans every visible device;
-    ``num_shards > len(jax.devices())`` wraps shards around the available
-    devices (several pools per device — the in-process test/dev mode)."""
+    survive across callers.  ``num_shards=0`` spans every visible device.
+    On CPU, ``num_shards > len(jax.devices())`` wraps shards around the
+    available devices (several pools per device — the in-process test/dev
+    mode); on an accelerator it raises, since stacking pools on one chip
+    would silently serve a smaller fleet than asked for."""
     pool = pool or PoolConfig()
     k = (cfg, pool, num_shards)
     if k in _ROUTERS:
         _ROUTERS[k] = _ROUTERS.pop(k)          # refresh LRU position
         return _ROUTERS[k]
+    devs = list(jax.devices())
+    if num_shards > len(devs) and devs[0].platform != "cpu":
+        raise ValueError(
+            f"{num_shards} shards asked for, but only {len(devs)} "
+            f"{devs[0].platform} devices are visible (one shard per device)"
+        )
     while len(_ROUTERS) >= _MAX_ROUTERS:
         _ROUTERS.pop(next(iter(_ROUTERS)))
-    devs = list(jax.devices())
     if num_shards:
         devs = [devs[i % len(devs)] for i in range(num_shards)]
     _ROUTERS[k] = ShardedEngine(cfg, pool, devices=devs)

@@ -15,6 +15,7 @@ import dataclasses
 import os
 import subprocess
 import sys
+import types
 from pathlib import Path
 
 import jax
@@ -168,6 +169,17 @@ class TestRouterTokenIdentity:
                 sh.compiles, sh.num_buckets
             )
         assert eng.compiles == sum(sh.compiles for sh in eng.shards)
+
+    def test_more_shards_than_chips_raises_off_cpu(self, monkeypatch):
+        """On an accelerator, asking for more shards than chips is an
+        error: wrapping would stack pools on one chip."""
+        from repro.serve import router
+
+        cfg, _ = _setup()
+        chip = types.SimpleNamespace(platform="tpu")
+        monkeypatch.setattr(router.jax, "devices", lambda: [chip])
+        with pytest.raises(ValueError, match="1 tpu devices"):
+            router.sharded_engine(cfg, PoolConfig(), num_shards=2)
 
     def test_placement_prefers_freest_shard(self):
         """With shard0 loaded and shard1 idle, the next admission must go
@@ -366,6 +378,8 @@ assert len(done) == len(lengths)
 assert all(c > 0 for c in eng.placement_counts), eng.placement_counts
 for sh in eng.shards:
     assert sh.compiles == sh.num_buckets + 1, (sh.compiles, sh.num_buckets)
+    assert sh.devices_in_use() == {sh.device}, (sh.devices_in_use(), sh.device)
+assert len({sh.device for sh in eng.shards}) == 4
 for i, req in enumerate(reqs):
     ref, _ = generate_reference(
         params, cfg, req.prompt[None], req.max_tokens,
