@@ -924,11 +924,13 @@ def main():
     ap.add_argument(
         "--obs-dir", default=None,
         help="enable the obs registry and write obs_events.jsonl / "
-             "obs_metrics.prom / obs_trace.json artifacts here",
+             "obs_metrics.prom artifacts here",
     )
     ap.add_argument(
         "--profile-dir", default=None,
-        help="wrap the run in jax.profiler.trace (TensorBoard dump)",
+        help="wrap the run in jax.profiler.trace (TensorBoard / xprof dump): "
+             "the serve.* spans on the host timeline and the di_* / stack_* "
+             "scopes on the device's ops, on one clock",
     )
     ap.add_argument(
         "--assert-obs-span-chain", action="store_true",
@@ -1120,9 +1122,6 @@ def main():
         exporters.write_jsonl(reg, os.path.join(args.obs_dir, "obs_events.jsonl"))
         exporters.write_prometheus(
             reg, os.path.join(args.obs_dir, "obs_metrics.prom")
-        )
-        exporters.write_chrome_trace(
-            reg, os.path.join(args.obs_dir, "obs_trace.json")
         )
         logger.info(f"obs artifacts -> {args.obs_dir}/")
 

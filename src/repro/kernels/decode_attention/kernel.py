@@ -204,6 +204,7 @@ def flash_decode_kernel(
         out_specs=pl.BlockSpec((1, 1, r, lw), lambda i, hb: (i, hb, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
         interpret=pallas_interpret(interpret),
+        name="flash_decode_kernel",
     )(*args)
     return _collapse_out(out, hpb, g, hd)
 
@@ -335,6 +336,7 @@ def paged_flash_decode_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
         interpret=pallas_interpret(interpret),
+        name="paged_flash_decode_kernel",
     )(jnp.asarray(n_valid, jnp.int32), jnp.asarray(block_table, jnp.int32),
       *args)
     return _collapse_out(out, hpb, g, hd)

@@ -277,12 +277,14 @@ def forward(
         link_fn=link_fn,
         mode=mode,
     )
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), new_cache, aux
+    with jax.named_scope("di_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = x @ params["lm_head"]
+        logits = logits.astype(jnp.float32)
+    return logits, new_cache, aux
 
 
 def lm_loss(

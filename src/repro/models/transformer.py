@@ -158,43 +158,53 @@ def run_stack(
     aux = jnp.zeros((), jnp.float32)
     with_cache = cache is not None
 
+    # Device scopes (``jax.named_scope``) name the split's parts in the
+    # compiled program's op metadata, so a profile attributes device time to
+    # them: ``di_device_half``, ``di_link``, ``di_server_half``, and the
+    # per-segment slicing of weights and caches (``stack_split``) and the
+    # caches' concatenation (``stack_merge``).
     # --- prologue (unrolled) ---
     new_pro = []
-    for i, spec in enumerate(cfg.prologue):
-        c_i = cache["prologue"][i] if with_cache else None
-        x, nc, a = layer_forward(
-            params["prologue"][i], x, cfg, spec, positions, c_i, cache_index
-        )
-        aux = aux + a
-        new_pro.append(nc)
+    with jax.named_scope("di_device_half"):
+        for i, spec in enumerate(cfg.prologue):
+            c_i = cache["prologue"][i] if with_cache else None
+            x, nc, a = layer_forward(
+                params["prologue"][i], x, cfg, spec, positions, c_i, cache_index
+            )
+            aux = aux + a
+            new_pro.append(nc)
 
     body = _unit_body(cfg, positions, cache_index, with_cache)
     if mode == "train" and cfg.remat:
         body = jax.checkpoint(body)
 
-    def scan_segment(x, aux, lo, hi):
+    def scan_segment(x, aux, lo, hi, scope):
         if hi <= lo:
             return x, aux, None
-        p_seg = _slice_units(params["units"], lo, hi)
-        if with_cache:
-            c_seg = [_slice_units(c, lo, hi) for c in cache["units"]]
-            (x, aux), ys = jax.lax.scan(body, (x, aux), (p_seg, c_seg))
-        else:
-            (x, aux), ys = jax.lax.scan(body, (x, aux), p_seg)
+        with jax.named_scope("stack_split"):
+            xs = _slice_units(params["units"], lo, hi)
+            if with_cache:
+                xs = (xs, [_slice_units(c, lo, hi) for c in cache["units"]])
+        with jax.named_scope(scope):
+            (x, aux), ys = jax.lax.scan(body, (x, aux), xs)
         return x, aux, ys
 
-    x, aux, ys1 = scan_segment(x, aux, 0, split if link_fn is not None else 0)
+    x, aux, ys1 = scan_segment(
+        x, aux, 0, split if link_fn is not None else 0, "di_device_half"
+    )
     if link_fn is not None:
-        x = link_fn(x)
-    x, aux, ys2 = scan_segment(x, aux, split, u)
+        with jax.named_scope("di_link"):
+            x = link_fn(x)
+    x, aux, ys2 = scan_segment(x, aux, split, u, "di_server_half")
 
     new_cache = None
     if with_cache:
         segs = [s for s in (ys1, ys2) if s is not None]
         if len(segs) == 2:
-            new_units = jax.tree_util.tree_map(
-                lambda a, b: jnp.concatenate([a, b], axis=0), segs[0], segs[1]
-            )
+            with jax.named_scope("stack_merge"):
+                new_units = jax.tree_util.tree_map(
+                    lambda a, b: jnp.concatenate([a, b], axis=0), segs[0], segs[1]
+                )
         else:
             new_units = segs[0]
         new_cache = {"prologue": new_pro, "units": new_units}

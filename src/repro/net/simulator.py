@@ -353,9 +353,8 @@ def _publish_obs(reg, report: SimReport, done: Sequence[_Request],
                  t_wall0: float) -> None:
     """Registry export of one simulation.  Per-request spans are recorded
     on the *simulated* clock, rebased onto the registry's epoch
-    (``reg.perf0 + sim_time``) so a chrome trace of the event log shows the
-    sim timeline starting at 0 — wall time only stamps the ``sim.run``
-    span itself."""
+    (``reg.perf0 + sim_time``) so the event log shows the sim timeline
+    starting at 0 — wall time only stamps the ``sim.run`` span itself."""
     reg.record_span(
         "sim.run", t_wall0, time.perf_counter(),
         arrived=report.arrived, served=report.served,

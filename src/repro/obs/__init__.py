@@ -3,13 +3,14 @@
 Three layers (see README "Observability"):
 
 * ``registry()`` — the process-global ``Registry``: counters, gauges,
-  streaming histograms, nested spans.  Disabled by default (true no-op);
-  enable with ``obs.enable()`` or ``REPRO_OBS=1``.
+  streaming histograms, nested spans.  Disabled by default (a no-op but
+  for ``span()``, which always opens a profiler annotation); enable with
+  ``obs.enable()`` or ``REPRO_OBS=1``.
 * ``device`` — trace-time taps that turn link-mask draws inside jitted
   programs into the ``DeviceCounters`` pytree threaded through the
   slot-pool engine state (harvested host-side only at sync points).
-* ``exporters`` — JSONL event log, Prometheus text, chrome://tracing
-  trace, and the ``jax.profiler.trace`` wrapper.
+* ``exporters`` — JSONL event log, Prometheus text, and the
+  ``jax.profiler.trace`` wrapper.
 """
 
 from repro.obs import device, exporters, stats, xla

@@ -1,16 +1,15 @@
-"""Registry exporters: JSONL events, Prometheus text, chrome://tracing.
+"""Registry exporters: JSONL events and Prometheus text.
 
-All three read only ``Registry.snapshot()`` and ``Registry.events``:
+Both read only ``Registry.snapshot()`` and ``Registry.events``:
 
 * ``write_jsonl`` — one JSON object per line: a header record (wall-clock
   anchor + metric snapshot) followed by every event in emission order.
 * ``write_prometheus`` — the text exposition format: counters, gauges,
   and histogram quantiles as ``name{quantile="0.5"}`` summary series.
-* ``write_chrome_trace`` — a ``chrome://tracing`` / Perfetto JSON file:
-  spans become complete ("ph": "X") events with microsecond timestamps,
-  instants become "ph": "i"; load it at chrome://tracing or ui.perfetto.dev.
 * ``jax_profile`` — optional ``jax.profiler.trace`` wrapper (the
   ``--profile-dir`` flag): a no-op context when the directory is None.
+  The timeline lives there: every registry span is also a profiler
+  annotation, on the same clock as the device's ops.
 
 ``request_chain_rids`` is the span-chain checker the CI obs smoke asserts
 with: the rids whose submit→retire lifecycle is fully covered.
@@ -74,34 +73,6 @@ def prometheus_text(reg: Registry) -> str:
 def write_prometheus(reg: Registry, path: str) -> None:
     with open(path, "w") as f:
         f.write(prometheus_text(reg))
-
-
-def chrome_trace(reg: Registry) -> Dict:
-    """Trace-event JSON: one process, spans on thread 0 with µs stamps
-    relative to the registry's perf epoch."""
-    t0 = reg.perf0
-    trace_events = []
-    for ev in reg.events:
-        base = {
-            "name": ev["name"],
-            "pid": 1,
-            "tid": 0,
-            "ts": (ev["t"] - t0) * 1e6,
-            "args": ev.get("attrs", {}),
-        }
-        if ev["kind"] == "span":
-            base["ph"] = "X"
-            base["dur"] = ev["dur"] * 1e6
-        else:
-            base["ph"] = "i"
-            base["s"] = "g"
-        trace_events.append(base)
-    return {"traceEvents": trace_events, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(reg: Registry, path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(chrome_trace(reg), f)
 
 
 def request_chain_rids(reg: Registry) -> Set[int]:

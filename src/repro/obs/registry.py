@@ -1,11 +1,15 @@
 """Process-global metrics + tracing registry.
 
 One ``Registry`` per process (``obs.registry()``), disabled by default
-(enable with ``obs.enable()`` or ``REPRO_OBS=1``).  Disabled, every API is
-a true no-op: ``counter()``/``gauge()``/``histogram()`` return shared null
-singletons whose methods do nothing, ``span()`` returns a reusable null
-context manager, and no events are stored — the hot-path cost is one
-attribute load and one branch.
+(enable with ``obs.enable()`` or ``REPRO_OBS=1``).  Disabled, every API but
+``span()`` is a true no-op: ``counter()``/``gauge()``/``histogram()``
+return shared null singletons whose methods do nothing, and no events are
+stored — the hot-path cost is one attribute load and one branch.
+
+``span()`` is the program's one span call.  On or off, it opens a
+``jax.profiler.TraceAnnotation`` of the span's name, which lands on the
+profiler's host timeline, on the device planes' clock, whenever a profiler
+session is active (and costs about a microsecond of host time when none is).
 
 Enabled, it holds:
 
@@ -19,8 +23,8 @@ Enabled, it holds:
   are ``time.perf_counter()`` seconds; ``epoch0``/``perf0`` in
   ``snapshot()`` anchor them to wall time.
 
-Exporters (JSONL / Prometheus text / chrome://tracing) live in
-``obs.exporters`` and read only ``snapshot()`` + ``events``.
+Exporters (JSONL / Prometheus text) live in ``obs.exporters`` and read
+only ``snapshot()`` + ``events``.
 """
 
 from __future__ import annotations
@@ -31,6 +35,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.stats import StreamingHistogram
 
@@ -85,20 +91,9 @@ class _NullHistogram:
         return {}
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
 _NULL_COUNTER = _NullCounter()
 _NULL_GAUGE = _NullGauge()
 _NULL_HISTOGRAM = _NullHistogram()
-_NULL_SPAN = _NullSpan()
 
 
 class Registry:
@@ -192,7 +187,8 @@ class Registry:
         stack.append(sid)
         t0 = time.perf_counter()
         try:
-            yield sid
+            with TraceAnnotation(name):
+                yield sid
         finally:
             t1 = time.perf_counter()
             stack.pop()
@@ -207,10 +203,11 @@ class Registry:
             self._append(ev)
 
     def span(self, name: str, **attrs):
-        """Context manager: a nested span with monotonic start/stop.  The
-        disabled path returns a shared null manager (no allocation)."""
+        """Context manager: a profiler annotation named ``name`` (without
+        the attrs), and with the registry enabled also a nested span event
+        with monotonic start/stop and the attrs."""
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(name)
         return self._live_span(name, attrs)
 
     def record_span(

@@ -185,8 +185,14 @@ class Request:
     @property
     def ttft_s(self) -> float:
         """Time to first token, from submission (includes queue wait).
-        Honest — blocked on device — only with the obs registry enabled;
-        otherwise it is a dispatch-time stamp (a lower bound)."""
+
+        An upper bound.  The engine adds no device sync to stamp it:
+        ``t_first_token`` is taken at the first sync it makes anyway after
+        the prefill's dispatch (a completion step's ``block_until_ready``
+        or a harvest), with the registry on or off alike.  When the
+        admitting tick completes a request, as in steady traffic, that is
+        the end of the same tick's decode step, so the stamp is at most
+        one step late; otherwise it waits for the next such sync."""
         return self.t_first_token - self.t_submit
 
     @property
@@ -325,6 +331,9 @@ class ContinuousEngine:
         self._remaining: List[int] = [0] * self.pool.max_slots
         self._free: List[int] = list(range(self.pool.max_slots))
         self._pending_harvest: List[Tuple[int, Request]] = []
+        # Admitted requests whose first token the host has not yet seen
+        # (stamped at the next existing device sync; see Request.ttft_s).
+        self._awaiting_first: List[Request] = []
         self._finished: List[Request] = []
         self._req_metrics: collections.deque = collections.deque(maxlen=4096)
         self._rid = 0
@@ -465,25 +474,25 @@ class ContinuousEngine:
                 with obs_device.tap_link_stats() as tap:
                     if pool.greedy:
                         key2, sub = jax.random.split(key)
-                        logits, new_cache = step(
-                            params, token, cache, length, sub
-                        )
+                    else:
+                        key2, sub, ks = jax.random.split(key, 3)
+                    logits, new_cache = step(params, token, cache, length, sub)
+                    link = tap.totals()
+                with jax.named_scope("di_sample"):
+                    if pool.greedy:
                         nxt = jnp.argmax(logits, axis=-1)[:, None].astype(
                             jnp.int32
                         )
                     else:
-                        key2, sub, ks = jax.random.split(key, 3)
-                        logits, new_cache = step(
-                            params, token, cache, length, sub
-                        )
                         scaled = logits.astype(jnp.float32) / jnp.float32(
                             max(pool.temperature, 1e-6)
                         )
                         nxt = jax.random.categorical(ks, scaled, axis=-1)[
                             :, None
                         ].astype(jnp.int32)
-                    link = tap.totals()
-                out2 = jax.lax.dynamic_update_slice(out_row, token[0], (n_gen,))
+                    out2 = jax.lax.dynamic_update_slice(
+                        out_row, token[0], (n_gen,)
+                    )
                 sel = lambda a, b: jnp.where(live, a, b)
                 # NOTE: new_cache is NOT select-masked — a retired slot's
                 # dirty write lands at its frozen length (never read past
@@ -589,19 +598,20 @@ class ContinuousEngine:
                 )
                 link = tap.totals()
             last = logits[:, 0]                                  # (B, V)
-            if pool.greedy:
-                nxt = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
-            else:
-                scaled = last.astype(jnp.float32) / jnp.float32(
-                    max(pool.temperature, 1e-6)
-                )
-                nxt = jax.vmap(jax.random.categorical)(kcat, scaled)[
-                    :, None
-                ].astype(jnp.int32)
-            # Emit the token fed INTO the round (reference-loop order).
-            out2 = jax.vmap(
-                lambda row, t, n: jax.lax.dynamic_update_slice(row, t, (n,))
-            )(state["out"], tokens[:, 0:1], state["n_gen"])
+            with jax.named_scope("di_sample"):
+                if pool.greedy:
+                    nxt = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
+                else:
+                    scaled = last.astype(jnp.float32) / jnp.float32(
+                        max(pool.temperature, 1e-6)
+                    )
+                    nxt = jax.vmap(jax.random.categorical)(kcat, scaled)[
+                        :, None
+                    ].astype(jnp.int32)
+                # Emit the token fed INTO the round (reference-loop order).
+                out2 = jax.vmap(
+                    lambda row, t, n: jax.lax.dynamic_update_slice(row, t, (n,))
+                )(state["out"], tokens[:, 0:1], state["n_gen"])
             livec = live[:, None]
             livef = live.astype(jnp.float32)
             valid = (state["length"] + 1).astype(jnp.float32)
@@ -666,19 +676,20 @@ class ContinuousEngine:
                     link_key=sub, link_mode="serve", mode="prefill",
                 )
                 link = tap.totals()
-            last = jax.lax.dynamic_slice(
-                logits, (0, true_len - 1, 0), (1, 1, logits.shape[-1])
-            )[:, 0]                                   # (1, V): true last pos
-            if pool.greedy:
-                tok0 = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
-            else:
-                key, ks = jax.random.split(key)
-                scaled = last.astype(jnp.float32) / jnp.float32(
-                    max(pool.temperature, 1e-6)
-                )
-                tok0 = jax.random.categorical(ks, scaled, axis=-1)[
-                    :, None
-                ].astype(jnp.int32)
+            with jax.named_scope("di_sample"):
+                last = jax.lax.dynamic_slice(
+                    logits, (0, true_len - 1, 0), (1, 1, logits.shape[-1])
+                )[:, 0]                               # (1, V): true last pos
+                if pool.greedy:
+                    tok0 = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
+                else:
+                    key, ks = jax.random.split(key)
+                    scaled = last.astype(jnp.float32) / jnp.float32(
+                        max(pool.temperature, 1e-6)
+                    )
+                    tok0 = jax.random.categorical(ks, scaled, axis=-1)[
+                        :, None
+                    ].astype(jnp.int32)
             set1 = lambda arr, v: arr.at[slot].set(v)
             c = state["obs"]
             new_obs = {
@@ -833,13 +844,15 @@ class ContinuousEngine:
         sla: Optional[SLA] = None,
     ) -> Request:
         """Queue one request; returns its handle (filled in by run())."""
-        req = build_request(self, self._rid, prompt, max_tokens, key, sla)
-        self._rid += 1
-        if self.scheduler is not None:
-            self.scheduler.enqueue(req)
-        else:
-            self._queue.append(req)
-        obs.registry().counter("serve.requests_submitted").inc()
+        reg = obs.registry()
+        with reg.span("serve.submit"):
+            req = build_request(self, self._rid, prompt, max_tokens, key, sla)
+            self._rid += 1
+            if self.scheduler is not None:
+                self.scheduler.enqueue(req)
+            else:
+                self._queue.append(req)
+            reg.counter("serve.requests_submitted").inc()
         return req
 
     def harvest(self) -> None:
@@ -859,19 +872,28 @@ class ContinuousEngine:
     def _harvest(self) -> None:
         if not self._pending_harvest:
             return
-        out = np.asarray(self._state["out"])    # one sync for the batch
-        now = time.perf_counter()
         reg = obs.registry()
-        for slot, req in self._pending_harvest:
-            req.tokens = out[slot, : req.max_tokens].copy()
-            req.t_retire = now
-            self._req_metrics.append(
-                {"ttft_s": req.ttft_s, "tpot_s": req.tpot_s,
-                 "e2e_s": req.e2e_s}
-            )
-            if reg.enabled:
-                self._emit_request_spans(reg, req, slot)
-        self._pending_harvest.clear()
+        with reg.span("serve.harvest"):
+            out = np.asarray(self._state["out"])    # one sync for the batch
+            now = time.perf_counter()
+            self._stamp_first_tokens(now)
+            for slot, req in self._pending_harvest:
+                req.tokens = out[slot, : req.max_tokens].copy()
+                req.t_retire = now
+                self._req_metrics.append(
+                    {"ttft_s": req.ttft_s, "tpot_s": req.tpot_s,
+                     "e2e_s": req.e2e_s}
+                )
+                if reg.enabled:
+                    self._emit_request_spans(reg, req, slot)
+            self._pending_harvest.clear()
+
+    def _stamp_first_tokens(self, now: float) -> None:
+        """Called right after a device sync: every prefill dispatched
+        before it has produced its first token by ``now``."""
+        for req in self._awaiting_first:
+            req.t_first_token = now
+        self._awaiting_first.clear()
 
     def _emit_request_spans(self, reg, req: Request, slot: int) -> None:
         """The submit→retire span chain, reconstructed from the stamps
@@ -934,6 +956,15 @@ class ContinuousEngine:
             need = self.blocks_needed(req.prompt.size, req.max_tokens)
             if need > len(self._free_blocks):
                 return False
+        with obs.registry().span("serve.admit"):
+            self._admit_into_slot(params, req, need)
+        return True
+
+    def _admit_into_slot(self, params, req: Request, need: int) -> None:
+        """The admission itself, once ``try_admit`` has found room: read
+        any finished rows first, upload the prompt and scalars, dispatch
+        the bucket's prefill."""
+        p = self.pool
         if self._pending_harvest:
             # A freed slot's output row is about to be zeroed: read the
             # finished requests first (one host sync for all of them).
@@ -977,14 +1008,7 @@ class ContinuousEngine:
             self.peak_blocks_used = max(self.peak_blocks_used, used)
             obs.registry().counter("serve.blocks_written").inc(nb)
             self._publish_pool_gauges()
-        if obs.registry().enabled:
-            # Honest TTFT: the first token is computed by the prefill
-            # program, so block on it before stamping.  Only with obs
-            # on — the disabled path keeps the async pipeline and the
-            # stamp is a dispatch-time lower bound.
-            jax.block_until_ready(self._state["token"])  # noqa: RPA005 — sanctioned sync point (honest TTFT, obs-on only)
-        req.t_first_token = time.perf_counter()
-        return True
+        self._awaiting_first.append(req)
 
     def _deaden_slot(self, slot: int) -> None:
         """Zero a slot's generation budget on device: the decode step's
@@ -1053,7 +1077,9 @@ class ContinuousEngine:
     def _decode_once(self, params) -> None:
         self.active_per_step.append(self.active)
         params = self._params_for(params)
-        self._state = self._decode_fn(params, self._state)
+        reg = obs.registry()
+        with reg.span("serve.decode"):
+            self._state = self._decode_fn(params, self._state)
         self.steps += 1
         completed = []
         for slot, req in enumerate(self._slot_req):
@@ -1080,8 +1106,10 @@ class ContinuousEngine:
             # dispatch-time stamp would under-report completion latency
             # whenever execution lags the host (the sync only happens on
             # completion steps, so steady-state steps still pipeline).
-            jax.block_until_ready(self._state["out"])  # noqa: RPA005 — sanctioned sync point (completion steps only; steady steps pipeline)
+            with reg.span("serve.sync"):
+                jax.block_until_ready(self._state["out"])  # noqa: RPA005 — sanctioned sync point (completion steps only; steady steps pipeline)
             now = time.perf_counter()
+            self._stamp_first_tokens(now)
             for slot, req in completed:
                 req.t_done = now
                 req.state = "completed"
@@ -1098,30 +1126,31 @@ class ContinuousEngine:
         FIFO otherwise), then run one fused decode step over the pool (if
         anything is live).  Unscheduled no-progress stalls are bounded by
         ``PoolConfig.exhaust_wait_steps`` → ``PoolExhausted``."""
-        self._ensure(params)
-        if self.scheduler is not None:
-            self.scheduler.tick(self, params)
-        else:
-            self._admit(params)
-        if self.active:
-            self._stalled_steps = 0
-            self._decode_once(params)
-        elif self.scheduler is None and self._queue:
-            self._stalled_steps += 1
-            if self._stalled_steps > self.pool.exhaust_wait_steps:
-                waited, self._stalled_steps = self._stalled_steps, 0
-                head = self._queue[0]
-                raise PoolExhausted(
-                    waited_steps=waited,
-                    queued=len(self._queue),
-                    free_slots=len(self._free),
-                    free_blocks=len(self._free_blocks),
-                    need_blocks=self.blocks_needed(
-                        head.prompt.size, head.max_tokens
-                    ) if self.pool.paged else 0,
-                )
-        else:
-            self._stalled_steps = 0
+        with obs.registry().span("serve.step"):
+            self._ensure(params)
+            if self.scheduler is not None:
+                self.scheduler.tick(self, params)
+            else:
+                self._admit(params)
+            if self.active:
+                self._stalled_steps = 0
+                self._decode_once(params)
+            elif self.scheduler is None and self._queue:
+                self._stalled_steps += 1
+                if self._stalled_steps > self.pool.exhaust_wait_steps:
+                    waited, self._stalled_steps = self._stalled_steps, 0
+                    head = self._queue[0]
+                    raise PoolExhausted(
+                        waited_steps=waited,
+                        queued=len(self._queue),
+                        free_slots=len(self._free),
+                        free_blocks=len(self._free_blocks),
+                        need_blocks=self.blocks_needed(
+                            head.prompt.size, head.max_tokens
+                        ) if self.pool.paged else 0,
+                    )
+            else:
+                self._stalled_steps = 0
 
     def run(self, params) -> List[Request]:
         """Drive until the queue and the pool are empty; returns every

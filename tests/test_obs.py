@@ -131,9 +131,10 @@ class TestRegistry:
         snap = reg.snapshot()
         assert snap["counters"] == {} and snap["gauges"] == {}
         assert snap["histograms"] == {} and reg.events == []
-        # The null singletons are shared (no per-call allocation).
+        # The null singletons are shared (no per-call allocation); a span
+        # is still a profiler annotation, which stores nothing here.
         assert reg.counter("a") is reg.counter("b")
-        assert reg.span("x") is reg.span("y")
+        assert isinstance(reg.span("x"), jax.profiler.TraceAnnotation)
 
     def test_enabled_metrics(self):
         reg = Registry(enabled=True)
@@ -186,7 +187,7 @@ class TestRegistry:
 
 
 # ---------------------------------------------------------------------------
-# Exporters: JSONL / Prometheus / chrome trace / span-chain checker
+# Exporters: JSONL / Prometheus / span-chain checker
 # ---------------------------------------------------------------------------
 
 def _chain_registry():
@@ -225,20 +226,6 @@ class TestExporters:
         assert '# TYPE serve_ttft_s summary' in text
         assert 'serve_ttft_s{quantile="0.50"}' in text
         assert "serve_ttft_s_count 1" in text
-
-    def test_chrome_trace_structure(self, tmp_path):
-        reg = _chain_registry()
-        reg.event("marker")
-        path = tmp_path / "trace.json"
-        exporters.write_chrome_trace(reg, str(path))
-        tr = json.loads(path.read_text())
-        evs = tr["traceEvents"]
-        assert len(evs) == len(reg.events)
-        complete = [e for e in evs if e["ph"] == "X"]
-        assert complete and all("dur" in e and e["dur"] >= 0 for e in complete)
-        assert any(e["ph"] == "i" for e in evs)
-        req = next(e for e in complete if e["name"] == "request")
-        assert req["dur"] == pytest.approx(1.0 * 1e6)   # microseconds
 
     def test_request_chain_rids(self):
         rids = exporters.request_chain_rids(_chain_registry())

@@ -15,6 +15,8 @@ file.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -173,3 +175,26 @@ def test_fused_decode_step(one_chip, paged, monkeypatch):
     state = place(jax.eval_shape(eng._init_state))
     step = jax.jit(eng._make_decode_step(), donate_argnums=(1,))
     assert "tpu_custom_call" in step.lower(params, state).compile().as_text()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_decode_step_names_its_kernel(one_chip, paged, monkeypatch):
+    """The decode step's lowered program names its Pallas call, with the
+    name the profiler shows for the kernel's op (the trace reductions
+    match it)."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.serve import ContinuousEngine, PoolConfig
+
+    monkeypatch.setenv("REPRO_FLASH_DECODE_IMPL", "kernel")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = get_config("qwen1.5-0.5b").with_updates(num_layers=2)
+    eng = ContinuousEngine(cfg, PoolConfig(max_prompt=64, max_new=32, paged=paged))
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = place(jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg)))
+    state = place(jax.eval_shape(eng._init_state))
+    text = jax.jit(eng._make_decode_step()).lower(params, state).as_text()
+    name = "paged_flash_decode_kernel" if paged else "flash_decode_kernel"
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [name]
