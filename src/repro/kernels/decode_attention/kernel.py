@@ -1,11 +1,12 @@
 """Flash-decode forward kernels (Pallas): length-masked online-softmax
 attention for the s == 1 decode step, with inline int8 dequantization.
 
-The model's cache layout is untouched; each wrapper views it lane-dense:
+Each wrapper reads the model's cache layout as it is, lane-dense:
 
 * q        — (B, KV, G, hd)   one query token, GQA-grouped
-* k / v    — (B, C, KV, hd)   rotating cache buffer (int8 codes or bf16),
-                              read as (B, C, KV*hd) — a free reshape
+* k / v    — (B, C, KV*hd)    rotating cache buffer (int8 codes or bf16),
+                              one row of KV*hd lanes per position (a
+                              (B, C, KV, hd) buffer is reshaped to it)
 * k/v scale— (B, C, KV)       per-(pos, head) bf16 absmax scales (int8 only)
 * n_valid  — (B, 1) int32     count of live cache slots for this request
 
@@ -168,7 +169,7 @@ def _make_kernel(*, block_kv, softcap, quantized, kvh, hpb, hd):
 )
 def flash_decode_kernel(
     q: jax.Array,                        # (B, KV, G, hd)
-    k: jax.Array,                        # (B, C, KV, hd) int8 or bf16/f32
+    k: jax.Array,                        # (B, C, KV*hd) or (B, C, KV, hd)
     v: jax.Array,
     k_scale: Optional[jax.Array],        # (B, C, KV) or None
     v_scale: Optional[jax.Array],
@@ -284,7 +285,7 @@ def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
 )
 def paged_flash_decode_kernel(
     q: jax.Array,                        # (B, KV, G, hd)
-    k: jax.Array,                        # (N, bs, KV, hd) block pool
+    k: jax.Array,                        # (N, bs, KV*hd) or (N, bs, KV, hd)
     v: jax.Array,
     k_scale: Optional[jax.Array],        # (N, bs, KV) or None
     v_scale: Optional[jax.Array],

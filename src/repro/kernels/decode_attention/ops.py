@@ -3,8 +3,9 @@ dispatch (Pallas kernel on accelerators, bit-identical jnp fallback on CPU).
 
 ``decode_attention`` consumes the model's decode state directly — the
 grouped query ``(B, 1, KV, G, hd)`` and the rotating cache dict in its
-native ``(B, C, KV, hd)`` layout (int8 codes + scales or bf16) — so no
-transposed/dequantized copy of the cache is ever materialized.  Dispatch:
+native layout, rows of ``KV * hd`` lanes ``(B, C, KV * hd)`` (a
+``(B, C, KV, hd)`` cache is taken too), int8 codes + scales or bf16 — so
+no transposed/dequantized copy of the cache is ever materialized.  Dispatch:
 
 * ``REPRO_FLASH_DECODE_IMPL=kernel|ref`` forces a path (tests/benchmarks);
 * otherwise the jnp fallback on CPU (a compiled interpret-mode Pallas call
@@ -67,9 +68,15 @@ def decode_block_kv(cache_len: int, block_kv: int) -> int:
     return g if g >= min(16, bkv) else bkv
 
 
+def _heads(x: jax.Array, kvh: int) -> jax.Array:
+    """Cache rows ``(B, C, KV * hd)`` as ``(B, C, KV, hd)``: the jnp
+    fallback walks one head at a time."""
+    return x.reshape(x.shape[:2] + (kvh, -1))
+
+
 def decode_attention(
     q: jax.Array,                        # (B, 1, KV, G, hd) grouped query
-    cache: Dict[str, Any],               # k/v (B, C, KV, hd) [+ k/v_scale]
+    cache: Dict[str, Any],               # k/v (B, C, KV*hd) [+ k/v_scale]
     n_valid: jax.Array,                  # scalar or (B,) live-slot count
     *,
     softcap: float = 0.0,
@@ -114,14 +121,15 @@ def decode_attention(
         )
     else:
         out = flash_decode_ref(
-            qh, k, v, k_scale, v_scale, n, block_kv=bkv, softcap=softcap
+            qh, _heads(k, kvh), _heads(v, kvh), k_scale, v_scale, n,
+            block_kv=bkv, softcap=softcap,
         )
     return out[:, None]
 
 
 def paged_decode_attention(
     q: jax.Array,                        # (B, 1, KV, G, hd) grouped query
-    pool: Dict[str, Any],                # k/v (N, bs, KV, hd) [+ k/v_scale]
+    pool: Dict[str, Any],                # k/v (N, bs, KV*hd) [+ k/v_scale]
     block_table: jax.Array,              # (B, J_max) int32 physical blocks
     n_valid: jax.Array,                  # (B,) live-row count per request
     *,
@@ -135,7 +143,7 @@ def paged_decode_attention(
 
     The paged twin of :func:`decode_attention`: same return contract
     ``(B, 1, KV, G, hd)`` in ``q.dtype``, but K/V rows live in
-    ``(num_blocks, block_size, KV, hd)`` pool buffers addressed through
+    ``(num_blocks, block_size, KV * hd)`` pool buffers addressed through
     each request's block-table row.  ``seq_len`` is static (the layer's
     ``cache_len``), so the table is sliced to this layer's
     ``ceil(seq_len / block_size)`` walkable blocks at trace time —
@@ -162,7 +170,7 @@ def paged_decode_attention(
         )
     else:
         out = paged_flash_decode_ref(
-            qh, k, v, k_scale, v_scale, bt, n,
+            qh, _heads(k, kvh), _heads(v, kvh), k_scale, v_scale, bt, n,
             block_size=block_size, softcap=softcap,
         )
     return out[:, None]

@@ -247,18 +247,22 @@ def init_kv_cache(
     kv_cache_dtype: str = "",
 ) -> Params:
     """bf16 cache, or int8 + per-(pos, head) bf16 scales (§Perf hillclimb 3:
-    decode is HBM-bound on the cache read; int8 halves cache bytes)."""
+    decode is HBM-bound on the cache read; int8 halves cache bytes).
+
+    K and V hold one row of ``num_kv * head_dim`` lanes per position,
+    ``(batch, length, num_kv * head_dim)``: the layout the decode kernel
+    reads.  A TPU tiles the two minor dims of an array, so a
+    ``(..., num_kv, head_dim)`` cache (16 x 64, or 4 x 128) would be padded
+    in its tiles and relaid out for the kernel on every read."""
+    rows = (batch, length, num_kv * head_dim)
     if kv_cache_dtype == "int8":
         return {
-            "k": jnp.zeros((batch, length, num_kv, head_dim), jnp.int8),
-            "v": jnp.zeros((batch, length, num_kv, head_dim), jnp.int8),
+            "k": jnp.zeros(rows, jnp.int8),
+            "v": jnp.zeros(rows, jnp.int8),
             "k_scale": jnp.zeros((batch, length, num_kv), jnp.bfloat16),
             "v_scale": jnp.zeros((batch, length, num_kv), jnp.bfloat16),
         }
-    return {
-        "k": jnp.zeros((batch, length, num_kv, head_dim), dtype),
-        "v": jnp.zeros((batch, length, num_kv, head_dim), dtype),
-    }
+    return {"k": jnp.zeros(rows, dtype), "v": jnp.zeros(rows, dtype)}
 
 
 def _quantize_kv(x: jax.Array):
@@ -279,39 +283,80 @@ def _is_quantized(cache: Params) -> bool:
     return "k_scale" in cache
 
 
-def _read_cache(cache: Params, dtype):
+def _read_cache(cache: Params, dtype, num_kv: Optional[int] = None):
+    """K and V as ``(B, C, KV, hd)``, dequantized.  ``num_kv`` splits the
+    rows of a bf16 cache into heads (an int8 cache's scales give it)."""
+    k, v = cache["k"], cache["v"]
+    if k.ndim == 3:
+        heads = cache["k_scale"].shape[-1] if _is_quantized(cache) else num_kv
+        k = k.reshape(k.shape[:2] + (heads, -1))
+        v = v.reshape(v.shape[:2] + (heads, -1))
     if _is_quantized(cache):
         return (
-            _dequantize_kv(cache["k"], cache["k_scale"], dtype),
-            _dequantize_kv(cache["v"], cache["v_scale"], dtype),
+            _dequantize_kv(k, cache["k_scale"], dtype),
+            _dequantize_kv(v, cache["v_scale"], dtype),
         )
-    return cache["k"], cache["v"]
+    return k, v
 
 
-def _write_decode(cache: Params, k: jax.Array, v: jax.Array, index) -> Params:
-    """Write one position (S==1) at rotating slot index % C."""
-    c = cache["k"].shape[1]
-    slot = index % c
-    upd = lambda buf, val: jax.lax.dynamic_update_slice_in_dim(buf, val, slot, axis=1)
+def _cache_rows(cache: Params, k: jax.Array, v: jax.Array) -> Params:
+    """New K/V ``(B, S, KV, hd)`` as the cache stores them: rows of
+    ``KV * hd`` lanes, int8 codes plus per-head scales for an int8 cache."""
+    rows = lambda a: a.reshape(a.shape[:2] + (-1,))
     if _is_quantized(cache):
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        return {
-            "k": upd(cache["k"], kq), "v": upd(cache["v"], vq),
-            "k_scale": upd(cache["k_scale"], ks),
-            "v_scale": upd(cache["v_scale"], vs),
-        }
-    return {"k": upd(cache["k"], k), "v": upd(cache["v"], v)}
+        return {"k": rows(kq), "v": rows(vq), "k_scale": ks, "v_scale": vs}
+    return {"k": rows(k), "v": rows(v)}
+
+
+def layer_of(cache: Params, unit) -> Params:
+    """The layer's own cache: ``cache`` itself, or unit ``unit`` of a cache
+    stacked over the stack's units (leading axis U) when ``unit`` is given."""
+    if unit is None:
+        return cache
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, unit, keepdims=False), cache
+    )
+
+
+def _write_rows(buf: jax.Array, val: jax.Array, row, unit) -> jax.Array:
+    """Write ``val`` ``(B, S, ...)`` at positions ``row...`` of axis 1 of a
+    layer's cache leaf ``buf``, or of unit ``unit`` of a stacked leaf: only
+    the new rows move.  One row is a scatter: vmapped over the slot pool it
+    stays one scatter, where a ``dynamic_update_slice`` becomes a loop
+    over the slots."""
+    if val.shape[1] == 1:
+        at = (jnp.arange(val.shape[0]), row)
+        return buf.at[at if unit is None else (unit,) + at].set(val[:, 0])
+    if unit is None:
+        return jax.lax.dynamic_update_slice_in_dim(buf, val, row, axis=1)
+    start = (unit, 0, row) + (0,) * (val.ndim - 2)
+    return jax.lax.dynamic_update_slice(buf, val[None], start)
+
+
+def _write_decode(cache: Params, k: jax.Array, v: jax.Array, index,
+                  unit=None) -> Params:
+    """Write one position (S==1) at rotating slot index % C (of unit
+    ``unit`` when the cache is stacked over units)."""
+    c = cache["k"].shape[1 if unit is None else 2]
+    slot = index % c
+    return {
+        name: _write_rows(cache[name], val, slot, unit)
+        for name, val in _cache_rows(cache, k, v).items()
+    }
 
 
 def _write_decode_paged(
-    cache: Params, k: jax.Array, v: jax.Array, idx: PagedIndex, c_len: int
+    cache: Params, k: jax.Array, v: jax.Array, idx: PagedIndex, c_len: int,
+    unit=None,
 ) -> Params:
     """Paged twin of :func:`_write_decode`: scatter each slot's one new
-    position into its block-table row.  Logical row ``lengths % c_len``
-    (same rotation as contiguous) maps to block ``row // block_size``,
-    offset ``row % block_size``; dead slots write trash block 0."""
-    bs = cache["k"].shape[1]
+    position into its block-table row (of unit ``unit`` when the pool is
+    stacked over units).  Logical row ``lengths % c_len`` (same rotation
+    as contiguous) maps to block ``row // block_size``, offset
+    ``row % block_size``; dead slots write trash block 0."""
+    bs = idx.block_size
     row = idx.lengths % c_len                                    # (B,)
     ent = jnp.take_along_axis(
         idx.block_table, (row // bs)[:, None], axis=1
@@ -320,17 +365,14 @@ def _write_decode_paged(
     rin = row % bs
 
     def upd(buf, val):
-        return buf.at[phys, rin].set(val)
+        if unit is None:
+            return buf.at[phys, rin].set(val[:, 0])
+        return buf.at[unit, phys, rin].set(val[:, 0])
 
-    if _is_quantized(cache):
-        kq, ks = _quantize_kv(k[:, 0])
-        vq, vs = _quantize_kv(v[:, 0])
-        return {
-            "k": upd(cache["k"], kq), "v": upd(cache["v"], vq),
-            "k_scale": upd(cache["k_scale"], ks),
-            "v_scale": upd(cache["v_scale"], vs),
-        }
-    return {"k": upd(cache["k"], k[:, 0]), "v": upd(cache["v"], v[:, 0])}
+    return {
+        name: upd(cache[name], val)
+        for name, val in _cache_rows(cache, k, v).items()
+    }
 
 
 def _concrete_index(cache_index) -> Optional[int]:
@@ -365,32 +407,30 @@ def _masked_decode_attn(
         n_valid = jnp.minimum(cache_index + 1, c)  # scalar
         valid = jnp.arange(c)[None, :] < n_valid   # (1, C)
     mask = valid[:, None, None, None, :]           # (1,1,1,1,C) -> bcast
-    k_read, v_read = _read_cache(cache, dtype)
+    k_read, v_read = _read_cache(cache, dtype, qg.shape[2])
     return _naive_attn(qg, k_read, v_read, mask, softcap)
 
 
-def _write_prefill(cache: Params, k: jax.Array, v: jax.Array) -> Params:
+def _write_prefill(cache: Params, k: jax.Array, v: jax.Array,
+                   unit=None) -> Params:
     """Write a full prefill (positions 0..S-1) consistent with rotating
-    decode writes: position p lands in slot p % C, keeping only the last C."""
-    c = cache["k"].shape[1]
+    decode writes: position p lands in slot p % C, keeping only the last C
+    (into unit ``unit`` when the cache is stacked over units)."""
+    c = cache["k"].shape[1 if unit is None else 2]
     s = k.shape[1]
-    quant = _is_quantized(cache)
-    if quant:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        parts = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        parts = {"k": k, "v": v}
-    out = {}
+    parts = _cache_rows(cache, k, v)
     if s <= c:
-        for name, val in parts.items():
-            out[name] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name], val, 0, axis=1
-            )
-        return out
+        return {
+            name: _write_rows(cache[name], val, 0, unit)
+            for name, val in parts.items()
+        }
     slots = (jnp.arange(c) + (s - c)) % c
+    out = {}
     for name, val in parts.items():
-        out[name] = cache[name].at[:, slots].set(val[:, s - c :])
+        layer = layer_of(cache[name], unit).at[:, slots].set(val[:, s - c :])
+        out[name] = layer if unit is None else jax.lax.dynamic_update_index_in_dim(
+            cache[name], layer, unit, 0
+        )
     return out
 
 
@@ -406,7 +446,12 @@ def attention_forward(
     positions: jax.Array,              # (B, S) or (B, 3, S)
     cache: Optional[Params] = None,
     cache_index=None,                  # scalar count of tokens already cached
+    unit=None,                         # this layer's unit in a stacked cache
 ) -> Tuple[jax.Array, Optional[Params]]:
+    """Returns (output, new cache).  With ``unit`` given, ``cache`` holds
+    every unit's cache of this layer stacked on a leading axis: the layer
+    writes its new K/V rows into unit ``unit`` of that stack, attends over
+    that unit's panel, and returns the stack."""
     b, s, _ = x.shape
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
 
@@ -436,36 +481,37 @@ def attention_forward(
         # pool has no contiguous layout for the naive oracle to read.
         idx = cache_index
         c = cache_len(spec, idx.max_seq)
-        new_cache = _write_decode_paged(cache, k, v, idx, c)
+        new_cache = _write_decode_paged(cache, k, v, idx, c, unit)
         from repro.kernels.decode_attention import paged_decode_attention
 
         n_valid = jnp.minimum(idx.lengths.astype(jnp.int32) + 1, c)
         out = paged_decode_attention(
-            qg, new_cache, idx.block_table, n_valid,
+            qg, layer_of(new_cache, unit), idx.block_table, n_valid,
             seq_len=c,
             block_size=idx.block_size,
             softcap=cfg.logit_softcap,
         )
     elif cache is not None and s == 1:
         # ---- decode: write one slot, attend over the rotating buffer ----
-        new_cache = _write_decode(cache, k, v, cache_index)
+        new_cache = _write_decode(cache, k, v, cache_index, unit)
+        layer = layer_of(new_cache, unit)
         if cfg.attn_impl in ("flash_decode", "blockwise"):
             # Length-masked flash decode: O(valid) cache blocks read,
             # int8 KV dequantized inline — the serve engines' default.
             from repro.kernels.decode_attention import decode_attention
 
-            c = new_cache["k"].shape[1]
+            c = layer["k"].shape[1]
             n_valid = jnp.minimum(
                 jnp.asarray(cache_index, jnp.int32) + 1, c
             )
             out = decode_attention(
-                qg, new_cache, n_valid,
+                qg, layer, n_valid,
                 softcap=cfg.logit_softcap,
                 block_kv=cfg.attn_decode_block_kv,
             )
         else:
             out = _masked_decode_attn(
-                qg, new_cache, cache_index, cfg.logit_softcap, k.dtype
+                qg, layer, cache_index, cfg.logit_softcap, k.dtype
             )
     else:
         # ---- train / prefill: self-attention over the fresh sequence ----
@@ -490,7 +536,7 @@ def attention_forward(
                 qg, k, v, msk[None, None, None, :, :], cfg.logit_softcap
             )
         if cache is not None:
-            new_cache = _write_prefill(cache, k, v)
+            new_cache = _write_prefill(cache, k, v, unit)
 
     out = out.reshape(b, s, h * hd)
     return out @ p["w_out"], new_cache

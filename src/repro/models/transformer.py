@@ -1,16 +1,15 @@
 """Unified decoder stack over heterogeneous layer kinds.
 
-The stack is a repeating ``unit_pattern`` of layers scanned with ``lax.scan``
-across ``U`` units (stacked params, leading axis U) plus an unrolled
-``prologue``.  The COMtune link layer splits the unit scan in two — the
-device-side scan and the server-side scan — so the split point is a
-first-class part of the lowered program.
+The stack is a repeating ``unit_pattern`` of layers looped over ``U`` units
+(stacked params, leading axis U) plus an unrolled ``prologue``.  The
+COMtune link layer splits the unit loop in two — the device-side loop and
+the server-side loop — so the split point is a first-class part of the
+lowered program.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +49,23 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec, dtype) -> Params:
     return p
 
 
+def _recurrent_forward(p: Params, x: jax.Array, cfg: ModelConfig,
+                       spec: LayerSpec, state: Optional[Params]):
+    """The mixer of a recurrent layer.  Returns (h, new_state)."""
+    if spec.kind == "mamba":
+        return mamba.mamba_forward(p, x, cfg, state)
+    if spec.kind == "mlstm":
+        if state is not None and x.shape[1] == 1:
+            return xlstm.mlstm_step(p, x, cfg, state)
+        # chunkwise-parallel form: O(S*chunk) memory instead of O(S^2)
+        # (§Perf hillclimb 2); returns the exact recurrent state.
+        h, st = xlstm.mlstm_chunked(p, x, cfg, state)
+        return h, (st if state is not None else None)
+    if spec.kind == "slstm":
+        return xlstm.slstm_forward(p, x, cfg, state)
+    raise ValueError(spec.kind)
+
+
 def layer_forward(
     p: Params,
     x: jax.Array,
@@ -58,27 +74,29 @@ def layer_forward(
     positions: jax.Array,
     cache: Optional[Params],
     cache_index,
+    unit=None,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Pre-norm residual layer. Returns (x, new_cache, aux_loss)."""
+    """Pre-norm residual layer. Returns (x, new_cache, aux_loss).
+
+    With ``unit`` given, ``cache`` is this layer's cache stacked over the
+    stack's units and only unit ``unit`` of it is read and written: new
+    K/V rows for attention, the whole (small) state for recurrent layers.
+    The returned cache is then the stack."""
     h_in = apply_norm(p["norm1"], x, cfg.norm)
     if spec.kind == "attn":
         h, new_cache = attention.attention_forward(
-            p["mix"], h_in, cfg, spec, positions, cache, cache_index
+            p["mix"], h_in, cfg, spec, positions, cache, cache_index, unit
         )
-    elif spec.kind == "mamba":
-        h, new_cache = mamba.mamba_forward(p["mix"], h_in, cfg, cache)
-    elif spec.kind == "mlstm":
-        if cache is not None and x.shape[1] == 1:
-            h, new_cache = xlstm.mlstm_step(p["mix"], h_in, cfg, cache)
-        else:
-            # chunkwise-parallel form: O(S*chunk) memory instead of O(S^2)
-            # (§Perf hillclimb 2); returns the exact recurrent state.
-            h, st = xlstm.mlstm_chunked(p["mix"], h_in, cfg, cache)
-            new_cache = st if cache is not None else None
-    elif spec.kind == "slstm":
-        h, new_cache = xlstm.slstm_forward(p["mix"], h_in, cfg, cache)
+    elif unit is None:
+        h, new_cache = _recurrent_forward(p["mix"], h_in, cfg, spec, cache)
     else:
-        raise ValueError(spec.kind)
+        h, state = _recurrent_forward(
+            p["mix"], h_in, cfg, spec, attention.layer_of(cache, unit)
+        )
+        new_cache = jax.tree_util.tree_map(
+            lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b, unit, 0),
+            cache, state,
+        )
     x = x + h
     aux = jnp.zeros((), jnp.float32)
     if _has_ffn(cfg, spec):
@@ -113,32 +131,50 @@ def init_stack(key, cfg: ModelConfig, dtype) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# Stack forward (two scan segments around the link split)
+# Stack forward (two loops around the link split)
 # ---------------------------------------------------------------------------
 
-def _unit_body(cfg: ModelConfig, positions, cache_index, with_cache: bool):
-    """Returns a scan body over one unit of layers."""
+def _unit_body(cfg: ModelConfig, positions):
+    """Scan body over one unit of layers, its weights as ``xs`` (no cache)."""
 
-    def body_fixed(carry, xs):
+    def body(carry, unit_params):
         x, aux = carry
-        if with_cache:
-            unit_params, unit_cache = xs
-        else:
-            unit_params, unit_cache = xs, [None] * len(cfg.unit_pattern)
-        new_caches = []
         for j, spec in enumerate(cfg.unit_pattern):
-            x, nc, a = layer_forward(
-                unit_params[j], x, cfg, spec, positions, unit_cache[j], cache_index
+            x, _, a = layer_forward(
+                unit_params[j], x, cfg, spec, positions, None, None
             )
             aux = aux + a
-            new_caches.append(nc)
-        return (x, aux), (new_caches if with_cache else None)
+        return (x, aux), None
 
-    return body_fixed
+    return body
 
 
 def _slice_units(tree, lo: int, hi: int):
     return jax.tree_util.tree_map(lambda a: a[lo:hi], tree)
+
+
+def _carried_units(params, cfg: ModelConfig, positions, cache_index, lo, hi,
+                   x, aux, caches):
+    """Units ``[lo, hi)`` over the whole stacked weights and the carried
+    stacked caches: unit ``l``'s weights are read at ``l`` and only what
+    unit ``l`` changes is written back into ``caches``."""
+
+    def body(l, carry):
+        x, aux, caches = carry
+        unit_params = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+            params,
+        )
+        caches = list(caches)
+        for j, spec in enumerate(cfg.unit_pattern):
+            x, caches[j], a = layer_forward(
+                unit_params[j], x, cfg, spec, positions, caches[j],
+                cache_index, unit=l,
+            )
+            aux = aux + a
+        return x, aux, caches
+
+    return jax.lax.fori_loop(lo, hi, body, (x, aux, list(caches)))
 
 
 def run_stack(
@@ -151,8 +187,14 @@ def run_stack(
     link_fn=None,
     mode: str = "train",
 ) -> Tuple[jax.Array, Optional[Dict[str, Any]], jax.Array]:
-    """Run prologue + unit scans, applying ``link_fn`` (the COMtune link
-    layer) at the configured split point.  Returns (x, new_cache, aux)."""
+    """Run prologue + unit loops, applying ``link_fn`` (the COMtune link
+    layer) at the configured split point.  Returns (x, new_cache, aux).
+
+    With a cache, both loops run over absolute unit indices of the whole
+    stacked weights and carry the whole stacked caches, so nothing is
+    copied at the split.  Without one (training, plain forwards), each
+    side scans its slice of the weights, whose gradients the scan stacks
+    per unit."""
     u = cfg.resolved_num_units
     split = min(max(cfg.link.split_after_units, 0), u) if link_fn is not None else 0
     aux = jnp.zeros((), jnp.float32)
@@ -160,9 +202,9 @@ def run_stack(
 
     # Device scopes (``jax.named_scope``) name the split's parts in the
     # compiled program's op metadata, so a profile attributes device time to
-    # them: ``di_device_half``, ``di_link``, ``di_server_half``, and the
-    # per-segment slicing of weights and caches (``stack_split``) and the
-    # caches' concatenation (``stack_merge``).
+    # them: ``di_device_half``, ``di_link``, ``di_server_half``, and, in
+    # programs without a cache, the per-segment slicing of the weights
+    # (``stack_split``).
     # --- prologue (unrolled) ---
     new_pro = []
     with jax.named_scope("di_device_half"):
@@ -174,38 +216,31 @@ def run_stack(
             aux = aux + a
             new_pro.append(nc)
 
-    body = _unit_body(cfg, positions, cache_index, with_cache)
+    caches = cache["units"] if with_cache else None
+    body = _unit_body(cfg, positions)
     if mode == "train" and cfg.remat:
         body = jax.checkpoint(body)
 
-    def scan_segment(x, aux, lo, hi, scope):
+    def segment(x, aux, caches, lo, hi, scope):
         if hi <= lo:
-            return x, aux, None
+            return x, aux, caches
+        if with_cache:
+            with jax.named_scope(scope):
+                return _carried_units(
+                    params["units"], cfg, positions, cache_index, lo, hi,
+                    x, aux, caches,
+                )
         with jax.named_scope("stack_split"):
             xs = _slice_units(params["units"], lo, hi)
-            if with_cache:
-                xs = (xs, [_slice_units(c, lo, hi) for c in cache["units"]])
         with jax.named_scope(scope):
-            (x, aux), ys = jax.lax.scan(body, (x, aux), xs)
-        return x, aux, ys
+            (x, aux), _ = jax.lax.scan(body, (x, aux), xs)
+        return x, aux, caches
 
-    x, aux, ys1 = scan_segment(
-        x, aux, 0, split if link_fn is not None else 0, "di_device_half"
-    )
+    x, aux, caches = segment(x, aux, caches, 0, split, "di_device_half")
     if link_fn is not None:
         with jax.named_scope("di_link"):
             x = link_fn(x)
-    x, aux, ys2 = scan_segment(x, aux, split, u, "di_server_half")
+    x, aux, caches = segment(x, aux, caches, split, u, "di_server_half")
 
-    new_cache = None
-    if with_cache:
-        segs = [s for s in (ys1, ys2) if s is not None]
-        if len(segs) == 2:
-            with jax.named_scope("stack_merge"):
-                new_units = jax.tree_util.tree_map(
-                    lambda a, b: jnp.concatenate([a, b], axis=0), segs[0], segs[1]
-                )
-        else:
-            new_units = segs[0]
-        new_cache = {"prologue": new_pro, "units": new_units}
+    new_cache = {"prologue": new_pro, "units": caches} if with_cache else None
     return x, new_cache, aux

@@ -203,7 +203,9 @@ def cache_pspecs(cfg: ModelConfig, shape_cfg: ShapeConfig, mesh: Mesh) -> Any:
     def attn_spec(spec: LayerSpec, stacked: bool):
         length = attention_lib.cache_len(spec, shape_cfg.seq_len)
         s_ax = seq_ax if (seq_ax and length % _axis_size(mesh, seq_ax) == 0) else None
-        base = (bs, s_ax, kv_ax, hd_ax)
+        # K/V rows are KV * hd lanes: sharding them over 'model' splits
+        # whole heads (kv_ax), or else an equal run of each row's lanes.
+        base = (bs, s_ax, kv_ax or hd_ax)
         kv = P(*(((None,) if stacked else ()) + base))
         out = {"k": kv, "v": kv}
         if cfg.kv_cache_dtype == "int8":

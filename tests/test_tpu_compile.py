@@ -198,3 +198,46 @@ def test_decode_step_names_its_kernel(one_chip, paged, monkeypatch):
     text = jax.jit(eng._make_decode_step()).lower(params, state).as_text()
     name = "paged_flash_decode_kernel" if paged else "flash_decode_kernel"
     assert re.findall(r'kernel_name = "(\w+)"', text) == [name]
+
+
+def test_decode_step_copies_no_stacked_segment(one_chip, monkeypatch):
+    """The contiguous decode step at qwen1.5-0.5b's widths, 4 layers with
+    the link after the first, compiled for a v5e: no concatenate, slice
+    or copy shaped like two or more units of the slot pool or of the
+    stacked weight matrices (a relayout of the pool, or a segment cut at
+    the split), and the pool's K/V alias the donated input."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.serve import ContinuousEngine, PoolConfig
+
+    monkeypatch.setenv("REPRO_FLASH_DECODE_IMPL", "kernel")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = get_config("qwen1.5-0.5b").with_updates(num_layers=4)
+    cfg = cfg.with_updates(link=dataclasses.replace(cfg.link, split_after_units=1))
+    eng = ContinuousEngine(cfg, PoolConfig(max_prompt=64, max_new=32))
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = place(jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg)))
+    state = place(jax.eval_shape(eng._init_state))
+    text = jax.jit(eng._make_decode_step(), donate_argnums=(1,)).lower(
+        params, state).compile().as_text()
+
+    def segments(tree, lead, min_ndim):
+        return {a.shape[:lead] + (n,) + a.shape[lead + 1:]
+                for a in jax.tree_util.tree_leaves(tree) if a.ndim >= min_ndim
+                for n in range(2, a.shape[lead] + 1)}
+
+    banned = (segments(params["stack"]["units"], 0, 3)
+              | segments(state["cache"]["units"], 1, 0))
+    ops = re.findall(r"\[([\d,]*)\]\{[^}]*\} (concatenate|slice|copy)\(", text)
+    for dims, op in ops:
+        assert tuple(int(d) for d in dims.split(",") if d) not in banned, (op, dims)
+    n_params = len(jax.tree_util.tree_leaves(params))
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", text))
+    leaves = jax.tree_util.tree_leaves_with_path(state)
+    cache = [n for n, (path, _) in enumerate(leaves)
+             if "cache" in jax.tree_util.keystr(path)]
+    assert cache and all(aliases.get(str(n)) == str(n_params + n) for n in cache)

@@ -7,7 +7,8 @@ observing the engine never changes its schedule.
   through ``obs.registry().span``, which always writes a profiler
   annotation, registry on or off.
 * The decode and prefill programs carry ``jax.named_scope`` names
-  (``di_*``, ``stack_*``) in every op's ``op_name``.
+  (``di_*``) in every op's ``op_name``; ``stack_split`` marks the weight
+  slices of a forward without a cache.
 * With the registry enabled the engine makes exactly the device syncs it
   makes with it off, and builds the same programs.
 """
@@ -104,23 +105,40 @@ class TestEngineSpans:
         assert set(SERVE_SPANS) <= names
 
 
+def _scopes(hlo_text):
+    """The ``di_*`` / ``stack_*`` scope names in a program's ``op_name``s."""
+    scopes = set()
+    for path in re.findall(r'op_name="([^"]*)"', hlo_text):
+        for c in path.split("/"):
+            m = re.fullmatch(r"(?:vmap\()*((?:di|stack)_\w+?)\)*", c)
+            if m:
+                scopes.add(m.group(1))
+    return scopes
+
+
 class TestDeviceScopes:
     @pytest.mark.parametrize("program", ["decode", "prefill"])
     def test_programs_carry_scopes(self, model, program):
+        """Every ``di_*`` scope is in the serving programs, and neither
+        ``stack_*`` scope: with a cache the stack runs over the whole
+        stacked weights and caches, so nothing is sliced or merged at the
+        split.  The forward without a cache still slices its weights."""
         cfg, params = model
         eng = ContinuousEngine(cfg, PoolConfig(max_slots=2, max_new=2, max_prompt=8))
         eng.submit(_prompt(3, 5, cfg.vocab_size), 2)
         eng.run(params)
         exe = eng.decode_executable if program == "decode" else eng._prefill_fns[8]
-        scopes = set()
-        for path in re.findall(r'op_name="([^"]*)"', exe.as_text()):
-            for c in path.split("/"):
-                m = re.fullmatch(r"(?:vmap\()*((?:di|stack)_\w+?)\)*", c)
-                if m:
-                    scopes.add(m.group(1))
+        scopes = _scopes(exe.as_text())
         want = {"di_device_half", "di_link", "di_server_half", "di_head",
-                "di_sample", "stack_split", "stack_merge"}
+                "di_sample"}
         assert want <= scopes, want - scopes
+        assert not scopes & {"stack_split", "stack_merge"}, scopes
+        tokens = _prompt(4, 8, cfg.vocab_size)[None]
+        train = jax.jit(lambda p, t: lm.forward(
+            p, t, cfg, link_key=jax.random.PRNGKey(0), link_mode="train"
+        )[0]).lower(params, tokens).compile()
+        assert {"di_device_half", "di_link", "di_server_half",
+                "stack_split"} <= _scopes(train.as_text())
 
 
 class TestScheduleUnchanged:
