@@ -3,10 +3,12 @@
 Everything that belongs to one cell is found by the names in
 ``BENCHMARK.json``: the configuration's file (``configs[].file``), the
 traffic mix ``bench/traffic/<traffic>.json``, the cell's limits
-``bench/limits/<workload>.json`` and one reader per per-layer metric,
+``bench/limits/<workload>.json``, one reader per per-layer metric,
 ``bench/metrics/<metric>.py`` (a function ``read(record)`` that returns a
-number, or None where it finds nothing to read).  A new cell, mix or
-metric is new files and entries; no code here changes.
+number, or None where it finds nothing to read), and the module of the
+configuration's architecture, ``bench/arch/<architectures[0]>.py``
+(``bench/model.py``).  A new cell, mix, metric or architecture is new
+files and entries; no code here changes.
 """
 
 from __future__ import annotations
@@ -14,7 +16,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import glob
-import importlib.util
 import json
 import math
 import os
@@ -26,6 +27,8 @@ from typing import Callable, Dict, List, Optional
 
 import jax
 from jax.profiler import TraceAnnotation as annotate  # noqa: F401  (serve, train use it)
+
+from bench import model
 
 TRACE_SPAN = "bench.traced"
 HOST_SPANS = (TRACE_SPAN, "generator", "engine.step", "take_finished",
@@ -155,7 +158,10 @@ def find_cell(root: Path, name: str):
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(wl)}")
     workload = wl[name]
     config = {c["name"]: c for c in spec["configs"]}[workload["config"]]
-    conf = _load_json(root / config["file"])
+    try:
+        conf = model.read_conf(root, config["file"])
+    except FileNotFoundError as e:
+        raise SystemExit(f"workload {name!r}: {e}") from None
     traffic = _load_json(root / "bench" / "traffic" / f"{workload['traffic']}.json")
     limits = _load_json(root / "bench" / "limits" / f"{name}.json")
     return spec, workload, conf, traffic, limits
@@ -177,12 +183,7 @@ def cell_metrics(spec: dict, workload: str, trace: bool) -> List[dict]:
 
 
 def read_metric(root: Path, name: str, rec: Record) -> Optional[float]:
-    path = root / "bench" / "metrics" / f"{name}.py"
-    modname = "bench_metric_" + "".join(c if c.isalnum() else "_" for c in name)
-    spec = importlib.util.spec_from_file_location(modname, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(rec)
+    return model.load_module(root / "bench" / "metrics" / f"{name}.py", "bench_metric_").read(rec)
 
 
 # ---------------------------------------------------------------------------

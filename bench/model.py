@@ -1,34 +1,99 @@
 """A configuration file as the program runs it, and its seeded weights.
 
 The configuration files under ``bench/configs`` use the keys of the
-model's published ``config.json``.  This module turns one into the
-program's ``ModelConfig`` (the system under test) and makes the weights
-from ``--seed``.  Weights are drawn layer by layer from
-``fold_in(key, layer)``, so the plain reference (``bench/reference.py``)
-can draw any one layer again without the program and without holding
-the whole model.
+model's published ``config.json``.  What depends on the architecture is in
+one module per architecture, ``bench/arch/<architectures[0]>.py`` under
+the root the configuration was read from (``read_conf``, ``arch``), found
+by name as the metric readers are; a configuration of a new architecture
+is then new files only.  Such a module provides:
+
+* ``program_config(conf, link, remat)``: the program's ``ModelConfig``
+  (the system under test), with ``link_config(conf, link)`` at the split;
+* ``program_tree(conf, key)``: the program's parameter pytree, less the
+  link's clip range, from the weight key; layer ``i`` drawn from
+  ``layer_key(key, i)``, the rest from ``outer_key(key)``;
+* ``canonical(tree)``: a tree shaped like the program's parameters under
+  the reference's names (training's comparison);
+* optionally ``layer_kind(conf, i)``: a hashable that tells the kinds of
+  layer apart by index (a dense layer 0 and expert layers after it, say);
+  without it every layer is of one kind, ``0``;
+* ``layer_weights(key, conf, kind)`` and ``outer_weights(key, conf)``: the
+  reference's float32 weights of a layer of that kind and of everything
+  outside the layers, from those same keys;
+* ``layer_forward(x, w, conf, prec, kind)``: one reference layer over
+  full causal sequences, written with ``bench/reference.py``'s ``mm``,
+  ``ein``, ``rmsnorm`` and ``rope`` (so that the float8 control covers
+  it), and optionally ``head_logits`` in place of the reference's;
+* ``param_count``, ``train_step_flops``, ``decode_steps`` and
+  ``decode_attention``: the operations and bytes that the benchmark
+  reads through ``bench/flops.py`` (which lists what else it may give).
+
+The reference compiles one program per kind of layer, not per layer.
+
+Shared here: the keys from the seed, the spread of the random weights,
+the COMtune link's configuration and clip range, the lookup, and the check
+that the weights have the program's own layout.  Weights are drawn layer
+by layer, so the plain reference can draw any one layer again without the
+program and without holding the whole model.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib.util
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-LAYER_LEAVES = ("ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo",
-                "ln2", "w_gate", "w_up", "w_down")
+OUTER = 1 << 20          # the fold-in of everything outside the layers
 # Spread of the random weights.  Matrices use 1/sqrt(fan_in); biases and
 # norm scales are small but non-zero so that a path that drops them shows.
 BIAS_STD, NORM_STD, EMBED_STD = 0.1, 0.1, 0.02
 
 
+def load_module(path: Path, prefix: str):
+    """The Python file at ``path`` as a module of its own."""
+    name = prefix + "".join(c if c.isalnum() else "_" for c in path.stem)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def load_arch(root: str, name: str):
+    path = Path(root) / "bench" / "arch" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"architecture {name!r}: no module at {path}")
+    return load_module(path, "bench_arch_")
+
+
+def arch(conf: dict):
+    """The module of the configuration's architecture, under the root it
+    was read from (``bench_root``)."""
+    return load_arch(conf["bench_root"], conf["architectures"][0])
+
+
+def read_conf(root: Path, file: str) -> dict:
+    """The configuration file ``file`` under ``root``, with the root
+    recorded as ``bench_root``.  An unknown architecture stops here,
+    before any weights are drawn."""
+    conf = dict(json.loads((Path(root) / file).read_text()), bench_root=str(root))
+    arch(conf)
+    return conf
+
+
+def layer_kind(conf: dict, i: int):
+    kind = getattr(arch(conf), "layer_kind", None)
+    return 0 if kind is None else kind(conf, i)
+
+
 def dims(conf: dict) -> dict:
-    d, h = conf["hidden_size"], conf["num_attention_heads"]
+    """Sizes that every architecture has."""
     return {
-        "d": d, "h": h, "kv": conf["num_key_value_heads"], "hd": d // h,
-        "ff": conf["intermediate_size"], "vocab": conf["vocab_size"],
+        "d": conf["hidden_size"], "vocab": conf["vocab_size"],
         "layers": conf["num_hidden_layers"],
         "split": conf["link"]["split_after_layers"],
         "tied": bool(conf["tie_word_embeddings"]),
@@ -45,97 +110,47 @@ def weight_key(seed: int) -> jax.Array:
     return jax.random.fold_in(seed_key(seed), 0x5EED)
 
 
-def _normal(key, shape, std):
+def layer_key(key, i) -> jax.Array:
+    return jax.random.fold_in(key, i)
+
+
+def outer_key(key) -> jax.Array:
+    return jax.random.fold_in(key, OUTER)
+
+
+def normal(key, shape, std):
     return jax.random.normal(key, shape, jnp.float32) * std
 
 
-def layer_weights(key, conf: dict) -> dict:
-    """One decoder layer's weights in float32 (before the cast to the
-    served dtype)."""
-    m = dims(conf)
-    d, q, kv, ff = m["d"], m["h"] * m["hd"], m["kv"] * m["hd"], m["ff"]
-    ks = dict(zip(LAYER_LEAVES, jax.random.split(key, len(LAYER_LEAVES))))
-    shapes = {
-        "ln1": ((d,), NORM_STD), "ln2": ((d,), NORM_STD),
-        "wq": ((d, q), d ** -0.5), "bq": ((q,), BIAS_STD),
-        "wk": ((d, kv), d ** -0.5), "bk": ((kv,), BIAS_STD),
-        "wv": ((d, kv), d ** -0.5), "bv": ((kv,), BIAS_STD),
-        "wo": ((q, d), q ** -0.5),
-        "w_gate": ((d, ff), d ** -0.5), "w_up": ((d, ff), d ** -0.5),
-        "w_down": ((ff, d), ff ** -0.5),
-    }
-    return {n: _normal(ks[n], s, std) for n, (s, std) in shapes.items()}
+def link_config(conf: dict, link: dict):
+    """The program's ``LinkConfig``: the configuration's link with the
+    channel of the traffic mix (``link``)."""
+    from repro.configs.base import LinkConfig
 
-
-def outer_weights(key, conf: dict) -> dict:
-    """Embedding, final norm scale and (untied) head, float32."""
-    m = dims(conf)
-    ke, kn, kh = jax.random.split(jax.random.fold_in(key, 1 << 20), 3)
-    out = {
-        "embed": _normal(ke, (m["vocab"], m["d"]), EMBED_STD),
-        "final_norm": _normal(kn, (m["d"],), NORM_STD),
-    }
-    if not m["tied"]:
-        out["lm_head"] = _normal(kh, (m["d"], m["vocab"]), m["d"] ** -0.5)
-    return out
+    lk = conf["link"]
+    return LinkConfig(
+        split_after_units=dims(conf)["split"],
+        dropout_rate=float(lk["dropout_rate"]),
+        loss_rate=float(link.get("loss_rate", 0.0)),
+        compression="quant", quant_bits=int(lk["quant_bits"]),
+        shuffle=bool(lk["shuffle"]),
+        channel=link.get("channel", "iid"),
+        channel_params=tuple(sorted(link.get("channel_params", {}).items())),
+    )
 
 
 def program_config(conf: dict, link: dict, remat: bool = True):
     """The program's ``ModelConfig`` for a configuration file, with the
     channel of the traffic mix (``link``) at the split."""
-    from repro.configs.base import LayerSpec, LinkConfig, ModelConfig
-
-    m = dims(conf)
-    lk = conf["link"]
-    return ModelConfig(
-        name=conf["name"], arch_type="dense", source=conf["source"],
-        num_layers=m["layers"], d_model=m["d"], num_heads=m["h"],
-        num_kv_heads=m["kv"], d_ff=m["ff"], vocab_size=m["vocab"],
-        qkv_bias=True, act=conf["hidden_act"], gated_mlp=True,
-        norm="rmsnorm", rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=m["tied"], unit_pattern=(LayerSpec(kind="attn"),),
-        link=LinkConfig(
-            split_after_units=m["split"],
-            dropout_rate=float(lk["dropout_rate"]),
-            loss_rate=float(link.get("loss_rate", 0.0)),
-            compression="quant", quant_bits=int(lk["quant_bits"]),
-            shuffle=bool(lk["shuffle"]),
-            channel=link.get("channel", "iid"),
-            channel_params=tuple(sorted(link.get("channel_params", {}).items())),
-        ),
-        dtype=conf["torch_dtype"], remat=remat,
-    )
+    return arch(conf).program_config(conf, link, remat)
 
 
 def _program_tree(conf: dict, key) -> dict:
-    m = dims(conf)
-    dtype = jnp.dtype(conf["torch_dtype"])
-    keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-        jnp.arange(m["layers"], dtype=jnp.uint32))
-    stacked = jax.lax.map(
-        lambda k: {n: a.astype(dtype) for n, a in layer_weights(k, conf).items()},
-        keys)
-    outer = outer_weights(key, conf)
     lo, hi = conf["link"]["clip"]
-    unit = {
-        "norm1": {"scale": stacked["ln1"]},
-        "mix": {"wq": stacked["wq"], "wk": stacked["wk"], "wv": stacked["wv"],
-                "w_out": stacked["wo"], "bq": stacked["bq"],
-                "bk": stacked["bk"], "bv": stacked["bv"]},
-        "norm2": {"scale": stacked["ln2"]},
-        "ffn": {"w_up": stacked["w_up"], "w_down": stacked["w_down"],
-                "w_gate": stacked["w_gate"]},
-    }
-    tree = {
-        "embed": outer["embed"].astype(dtype),
-        "stack": {"prologue": [], "units": [unit]},
-        "final_norm": {"scale": outer["final_norm"].astype(dtype)},
-        "link": {"s_min": jnp.full((m["d"],), lo, jnp.float32),
-                 "s_max": jnp.full((m["d"],), hi, jnp.float32)},
-    }
-    if "lm_head" in outer:
-        tree["lm_head"] = outer["lm_head"].astype(dtype)
-    return tree
+    d = dims(conf)["d"]
+    return dict(arch(conf).program_tree(conf, key),
+                link={"s_min": jnp.full((d,), lo, jnp.float32),
+                      "s_max": jnp.full((d,), hi, jnp.float32)})
 
 
 @functools.lru_cache(maxsize=4)

@@ -82,15 +82,13 @@ def decode_attention_roofline_pct(rec) -> Optional[float]:
 
 def decode_step_mfu_pct(rec) -> Optional[float]:
     """Least time the chip needs for the traced decode steps (every weight
-    read once a step, the valid K/V rows, the step's matmul FLOPs) over the
-    decode program's device time.  Bytes bind it."""
+    a step uses read once, the valid cache rows, the steps' matmul FLOPs)
+    over the decode program's device time.  Bytes bind it."""
     progs = decode_programs(rec)
     if not progs or not rec.counters.get("decode_steps"):
         return None
     sec, n = seconds_and_count(progs)
-    c = rec.counters
-    f, b = flops.decode_step(rec.cell.conf, c["live_slot_steps"], c["valid_rows"])
-    b += flops.weight_read_bytes(rec.cell.conf) * (n - 1)
+    f, b = flops.decode_steps(rec.cell.conf, n, rec.counters)
     return 100.0 * peaks.bound_seconds(f, b, _kind(rec)) / sec
 
 

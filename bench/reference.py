@@ -1,35 +1,42 @@
 """Plain reference of the split model, for the comparison that decides
 ``correct``.
 
-Written from the published descriptions alone (Qwen2 decoder layers as in
-Hugging Face's ``Qwen2ForCausalLM``; the COMtune link of arXiv 2112.09407
-Eq. 7, 8 and 12; Adam), in ``jax.numpy`` at float32 with matmuls at
-``HIGHEST`` precision.  It imports nothing of the program and takes
-nothing the program made: weights are drawn again from the seed
-(``bench/model.py``), one layer at a time, so that a reference of the
-7B configuration fits beside nothing else on one chip.
+Written from the published descriptions alone, in ``jax.numpy`` at
+float32 with matmuls at ``HIGHEST`` precision.  It imports nothing of the
+program and takes nothing the program made: weights are drawn again from
+the seed, one layer at a time, so that a reference of the 7B
+configuration fits beside nothing else on one chip.
+
+What is per architecture is in the architecture's module
+(``bench/arch/<architectures[0]>.py``, see ``bench/model.py``): the
+weights of a layer and of what lies outside the layers, one layer's
+forward pass (``layer_forward``, given the layer's kind, which the
+module may pick by index), and optionally the head.  Shared here by every architecture: the arithmetic (``mm``,
+``ein``, ``rmsnorm``, ``rope``) and its float8 control; the COMtune link
+(arXiv 2112.09407 Eq. 7, 8 and 12) and its Gilbert–Elliott channel; the
+loop over the layers with the link after ``split_after_layers``; the
+served-token gaps; the training loss, Adam and the per-leaf norms.
 
 ``prec="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 and every cotangent to e5m2, each with a per-tensor scale, the
 precision below the bfloat16 the configurations state.
 
-Departures from the published model, shared with the program: the norm
-scale is stored as ``w - 1`` (``x * (1 + scale)``); the link's lost
-elements are zero in the code domain, so a lost element decodes to the
-clip range's lower end (Eq. 12 taken literally).
+Departure from the published link, shared with the program: the link's
+lost elements are zero in the code domain, so a lost element decodes to
+the clip range's lower end (Eq. 12 taken literally).
 """
 
 from __future__ import annotations
 
 import functools
 import json
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import dims, layer_weights, outer_weights, weight_key
+from bench import model
+from bench.model import dims, weight_key
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -78,6 +85,7 @@ def ein(spec, a, b, prec):
 
 
 def rmsnorm(x, scale, eps):
+    """RMS norm with the scale stored as ``w - 1``, as the program stores it."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + scale)
 
 
@@ -91,34 +99,16 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def decoder_layer(x, w, conf, prec):
-    """One pre-norm Qwen2 layer over full causal sequences (B, S, d)."""
-    m = dims(conf)
-    b, s, _ = x.shape
-    eps = conf["rms_norm_eps"]
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    h = rmsnorm(x, w["ln1"], eps)
-    q = (mm(h, w["wq"], prec) + w["bq"]).reshape(b, s, m["h"], m["hd"])
-    k = (mm(h, w["wk"], prec) + w["bk"]).reshape(b, s, m["kv"], m["hd"])
-    v = (mm(h, w["wv"], prec) + w["bv"]).reshape(b, s, m["kv"], m["hd"])
-    q, k = rope(q, pos, conf["rope_theta"]), rope(k, pos, conf["rope_theta"])
-    g = m["h"] // m["kv"]
-    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    scores = ein("bqnd,bknd->bnqk", q, k, prec) / math.sqrt(m["hd"])
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    att = ein("bnqk,bknd->bqnd", probs, v, prec).reshape(b, s, m["h"] * m["hd"])
-    x = x + mm(att, w["wo"], prec)
-    h = rmsnorm(x, w["ln2"], eps)
-    up = jax.nn.silu(mm(h, w["w_gate"], prec)) * mm(h, w["w_up"], prec)
-    return x + mm(up, w["w_down"], prec)
-
-
 def head_logits(x, outer, conf, prec):
+    """Final norm and logits; an architecture may give its own."""
     x = rmsnorm(x, outer["final_norm"], conf["rms_norm_eps"])
     if "lm_head" in outer:
         return mm(x, outer["lm_head"], prec)
     return ein("bsd,vd->bsv", x, outer["embed"], prec)
+
+
+def _head(conf):
+    return getattr(model.arch(conf), "head_logits", head_logits)
 
 
 # ---------------------------------------------------------------------------
@@ -198,7 +188,9 @@ def _as_served(tree, conf):
 @functools.lru_cache(maxsize=8)
 def _layer_fn(conf_key):
     conf = json.loads(conf_key)
-    return jax.jit(lambda k: _as_served(layer_weights(k, conf), conf))
+    return jax.jit(
+        lambda k, kind: _as_served(model.arch(conf).layer_weights(k, conf, kind), conf),
+        static_argnums=1)
 
 
 def _conf_key(conf):
@@ -207,22 +199,27 @@ def _conf_key(conf):
 
 
 def layer_at(conf, seed, i):
-    return _layer_fn(_conf_key(conf))(jax.random.fold_in(weight_key(seed), i))
+    return _layer_fn(_conf_key(conf))(model.layer_key(weight_key(seed), i),
+                                      model.layer_kind(conf, i))
 
 
 @functools.lru_cache(maxsize=8)
 def _outer_fn(conf_key):
     conf = json.loads(conf_key)
-    return jax.jit(lambda k: _as_served(outer_weights(k, conf), conf))
+    return jax.jit(lambda k: _as_served(model.arch(conf).outer_weights(k, conf), conf))
 
 
 def outer_at(conf, seed):
-    return _outer_fn(_conf_key(conf))(weight_key(seed))
+    return _outer_fn(_conf_key(conf))(model.outer_key(weight_key(seed)))
 
 
 def all_weights(conf, seed):
-    """Every weight, layers stacked on a leading axis (training)."""
+    """Every weight, layers stacked on a leading axis (training), which
+    takes layers of one kind."""
     m = dims(conf)
+    if len({model.layer_kind(conf, i) for i in range(m["layers"])}) > 1:
+        raise ValueError(f"{conf['name']}: training's reference stacks the layers, "
+                         "which are not all of one kind")
     layers = [layer_at(conf, seed, i) for i in range(m["layers"])]
     stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *layers)
     return {"layers": stacked, **outer_at(conf, seed)}
@@ -232,9 +229,10 @@ def all_weights(conf, seed):
 # Serving: logits over prompt + served tokens
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _apply_layer(x, w, conf_key, prec):
-    return decoder_layer(x, w, json.loads(conf_key), prec)
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _apply_layer(x, w, conf_key, prec, kind):
+    conf = json.loads(conf_key)
+    return model.arch(conf).layer_forward(x, w, conf, prec, kind)
 
 
 def serve_hidden(conf, seed, tokens, keep, loss, prec="f32"):
@@ -247,7 +245,7 @@ def serve_hidden(conf, seed, tokens, keep, loss, prec="f32"):
     for i in range(m["layers"]):
         if i == m["split"]:
             x = serve_link(x, keep, conf, loss)
-        x = _apply_layer(x, layer_at(conf, seed, i), ck, prec)
+        x = _apply_layer(x, layer_at(conf, seed, i), ck, prec, model.layer_kind(conf, i))
     return x, outer
 
 
@@ -256,12 +254,12 @@ def _gaps(x_ref, x_ctl, outer, conf_key, with_control, served, pos_mask):
     """Per position: how far the served token's reference logit lies below
     the reference's best, and the same for the control's first choice."""
     conf = json.loads(conf_key)
-    ref = head_logits(x_ref[None], outer, conf, "f32")[0]
+    ref = _head(conf)(x_ref[None], outer, conf, "f32")[0]
     best = jnp.max(ref, -1)
     gap = best - jnp.take_along_axis(ref, served[:, None], -1)[:, 0]
     out = {"served": jnp.max(jnp.where(pos_mask, gap, 0.0))}
     if with_control:
-        ctl = head_logits(x_ctl[None], outer, conf, "fp8")[0]
+        ctl = _head(conf)(x_ctl[None], outer, conf, "fp8")[0]
         pick = jnp.argmax(ctl, -1)
         cgap = best - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
         out["control"] = jnp.max(jnp.where(pos_mask, cgap, 0.0))
@@ -333,19 +331,22 @@ def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False):
 # ---------------------------------------------------------------------------
 
 def _train_loss_sum(params, tokens, keep, conf, prec):
+    """Summed next-token loss, the layers stacked and scanned: one kind of
+    layer only (``all_weights``)."""
     m = dims(conf)
     x = jnp.take(params["embed"], tokens, axis=0)
     lay = params["layers"]
+    layer, kind = model.arch(conf).layer_forward, model.layer_kind(conf, 0)
 
     def run(x, lo, hi):
         seg = jax.tree_util.tree_map(lambda a: a[lo:hi], lay)
-        body = jax.checkpoint(lambda x, w: (decoder_layer(x, w, conf, prec), None))
+        body = jax.checkpoint(lambda x, w: (layer(x, w, conf, prec, kind), None))
         return jax.lax.scan(body, x, seg)[0]
 
     x = run(x, 0, m["split"])
     x = train_link(x, keep, conf)
     x = run(x, m["split"], m["layers"])
-    logits = head_logits(x, params, conf, prec)[:, :-1]
+    logits = _head(conf)(x, params, conf, prec)[:, :-1]
     lse = jax.nn.logsumexp(logits, -1)
     tgt = jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0]
     return jnp.sum(lse - tgt)

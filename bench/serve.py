@@ -164,12 +164,18 @@ def check_sample(conf: dict, traffic: dict, seed: int, sample, keys, control=Fal
 
 
 def _counters(engine) -> dict:
-    """Program counters at one instant (one device sync): decode steps and
-    valid K/V rows summed over live slots (on the device), and the
-    engine's host-side step and live-slot counts."""
+    """Program counters at one instant (one device sync): every running
+    total the program keeps on the device (``COUNTER_KEYS``; an
+    architecture's counts in ``bench/arch`` may read any of them), with
+    valid cache rows summed over live slots as ``valid_rows``; and the
+    engine's host-side step and live-slot counts.  Only totals: the traced
+    window takes the difference of its two readings."""
+    from repro.obs.device import COUNTER_KEYS
+
     dev = engine.device_counters()
-    return {"decode_steps": dev["decode_steps"], "valid_rows": dev["valid_tokens"],
-            "engine_steps": engine.steps, "live_slot_steps": engine.busy_slot_steps}
+    out = {k: dev[k] for k in COUNTER_KEYS}
+    out["valid_rows"] = out.pop("valid_tokens")
+    return dict(out, engine_steps=engine.steps, live_slot_steps=engine.busy_slot_steps)
 
 
 def run(cell) -> Record:

@@ -1,16 +1,20 @@
 """Every cell kind rehearsed end to end at a tiny size on the CPU, and the
 harness taking a new cell, mix and metric as data alone."""
 
+import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
+from pathlib import Path
 
+import jax
 import pytest
 
-from bench import harness
-from tinycells import CELLS, ROOT, TRAFFIC
+from bench import flops, harness, model, serve
+from tinycells import CELLS, ROOT, TINY, TRAFFIC
 
 E2E = {"serve": {"output_tokens_per_s", "request_latency_p95_ms", "setup_s"},
        "train": {"train_tokens_per_s", "setup_s"}}
@@ -65,6 +69,108 @@ def test_new_cell_mix_and_metric_are_data(tiny_root, capsys):
     assert "decode_step_ms.code" not in line["metrics"]   # no chip plane on the CPU
     assert line["device"]["window_s"] > 0
     assert "breakdown" in line
+
+
+def _add_cell(root, conf_name, arch, cell, limit):
+    """A configuration naming ``arch`` and a serve cell of it under the
+    open mix, as files and entries only."""
+    bench = root / "bench"
+    conf = dict(TINY, name=conf_name, source="test", architectures=[arch])
+    (bench / "configs" / f"{conf_name}.json").write_text(json.dumps(conf))
+    (bench / "limits" / f"{cell}.json").write_text(json.dumps({"served_gap": {"limit": limit}}))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": conf_name, "source": "test",
+                            "file": f"bench/configs/{conf_name}.json", "reduced": [],
+                            "why": "test"})
+    spec["workloads"].append({"name": cell, "config": conf_name, "traffic": "tiny-open",
+                              "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m and "tiny-serve-open" in m["workloads"]:
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
+def _bench_files(root):
+    return {p: hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in (root / "bench").rglob("*") if p.is_file() and "__pycache__" not in p.parts}
+
+
+# Made-up architectures, each a module under bench/tests and what its
+# program configuration must show: Qwen2 with no q, k or v biases; and
+# Qwen2 with two kinds of layer picked by index (a full-attention
+# prologue, then sliding-window layers).
+MADE_UP = {
+    "MadeUpNoBiasForCausalLM": ("nobias_arch.py", lambda cfg: cfg.qkv_bias is False),
+    "MadeUpWindowForCausalLM": ("window_arch.py",
+                                lambda cfg: len(cfg.prologue) == 1
+                                and cfg.unit_pattern[0].window == 8),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(MADE_UP))
+def test_new_architecture_is_files_only(tiny_root, capsys, monkeypatch, arch):
+    """A made-up architecture, added as its module, a configuration, a
+    cell and its limits, runs a serve cell to ``correct``; no file under
+    ``bench/`` is edited, and the float8 control reads above the program
+    (and the cell's limit) on it."""
+    from repro.models import lm
+
+    fixture, shows = MADE_UP[arch]
+    before = _bench_files(tiny_root)
+    shutil.copy(Path(__file__).with_name(fixture), tiny_root / "bench" / "arch" / f"{arch}.py")
+    limit = CELLS["tiny-serve-open"][2]["served_gap"]
+    _add_cell(tiny_root, "tiny-made-up", arch, "tiny-made-up-serve", limit)
+    after = _bench_files(tiny_root)
+    assert all(after[p] == h for p, h in before.items())
+
+    seen = {}
+    real = serve.check_sample
+
+    def with_control(conf, traffic, seed, sample, keys, control=False):
+        seen.update(real(conf, traffic, seed, sample, keys, control=True))
+        return seen
+
+    monkeypatch.setattr(serve, "check_sample", with_control)
+    rc, line, _ = run_cell(tiny_root, "tiny-made-up-serve", capsys)
+    assert rc == 0 and line["correct"] is True, line
+    assert seen["served"] <= limit < seen["control"], seen
+
+    conf = harness.find_cell(tiny_root, "tiny-made-up-serve")[2]
+    cfg = model.program_config(conf, {})
+    assert shows(cfg), cfg
+    shapes = jax.eval_shape(lambda: lm.init_lm(jax.random.PRNGKey(0), cfg))
+    assert flops.param_count(conf) == sum(a.size for a in jax.tree_util.tree_leaves(shapes))
+
+
+def test_unknown_architecture_names_the_path(tiny_root, capsys, monkeypatch):
+    """A configuration whose architecture has no module stops the run
+    before any weights are drawn, naming the path it looked for."""
+    _add_cell(tiny_root, "tiny-nowhere", "NoSuchForCausalLM", "tiny-nowhere-serve", 0.005)
+
+    def no_weights(*a, **k):
+        raise AssertionError("weights drawn")
+
+    monkeypatch.setattr(model, "program_params", no_weights)
+    with pytest.raises(SystemExit) as e:
+        run_cell(tiny_root, "tiny-nowhere-serve", capsys)
+    assert str(tiny_root / "bench" / "arch" / "NoSuchForCausalLM.py") in str(e.value)
+
+
+def test_counters_are_running_totals():
+    """The traced window differences two counter readings, so only running
+    totals are handed on: the program's derived drop rate is not."""
+    class Engine:
+        steps, busy_slot_steps = 5, 9
+
+        def device_counters(self):
+            return {"decode_steps": 3, "valid_tokens": 40.0, "decode_read_bytes": 8.0,
+                    "link_elems": 10.0, "link_dropped": 3.0, "fec_recovered_packets": 1.0,
+                    "realized_drop_rate": 0.3}
+
+    assert serve._counters(Engine()) == {
+        "decode_steps": 3, "valid_rows": 40.0, "decode_read_bytes": 8.0, "link_elems": 10.0,
+        "link_dropped": 3.0, "fec_recovered_packets": 1.0, "engine_steps": 5,
+        "live_slot_steps": 9}
 
 
 def test_no_tpu_no_result(tiny_root, capsys):

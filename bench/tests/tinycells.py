@@ -57,12 +57,15 @@ CELLS = {
 
 def build_root(path: Path) -> Path:
     """A directory laid out like a checkout: BENCHMARK.json naming the
-    tiny cells, their data files, and the real metric readers."""
+    tiny cells, their data files, and the real metric readers and
+    architecture modules."""
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     (path / "bench" / "configs").mkdir(parents=True)
     for sub in ("traffic", "limits"):
         (path / "bench" / sub).mkdir()
-    shutil.copytree(ROOT / "bench" / "metrics", path / "bench" / "metrics")
+    for sub in ("metrics", "arch"):
+        shutil.copytree(ROOT / "bench" / sub, path / "bench" / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     configs = []
     for name, tied in (("tiny", True), ("tiny-untied", False)):
         conf = dict(TINY, name=name, source="test", tie_word_embeddings=tied)

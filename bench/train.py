@@ -21,23 +21,13 @@ from bench import model, reference
 from bench.harness import Record, annotate, profile_window
 
 TRACE_AT, TRACE_S = 0.3, 3.0
-# The program's parameter tree, leaf by leaf, under the reference's names.
-UNIT_LEAVES = {("norm1", "scale"): "ln1", ("mix", "wq"): "wq", ("mix", "bq"): "bq",
-               ("mix", "wk"): "wk", ("mix", "bk"): "bk", ("mix", "wv"): "wv",
-               ("mix", "bv"): "bv", ("mix", "w_out"): "wo", ("norm2", "scale"): "ln2",
-               ("ffn", "w_gate"): "w_gate", ("ffn", "w_up"): "w_up",
-               ("ffn", "w_down"): "w_down"}
 
 
-def canonical(tree) -> dict:
-    """The program's parameter-shaped tree under the reference's names,
-    layers stacked on the leading axis."""
-    unit = tree["stack"]["units"][0]
-    out = {"layers": {name: unit[a][b] for (a, b), name in UNIT_LEAVES.items()},
-           "embed": tree["embed"], "final_norm": tree["final_norm"]["scale"]}
-    if "lm_head" in tree:
-        out["lm_head"] = tree["lm_head"]
-    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), out)
+def canonical(conf: dict, tree) -> dict:
+    """The program's parameter-shaped tree under the reference's names, in
+    float32, layers stacked on the leading axis."""
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                  model.arch(conf).canonical(tree))
 
 
 def feed(seed: int, vocab: int, k: int, b: int, s: int):
@@ -84,12 +74,13 @@ def first_epoch(compiled, params, opt, key, first, conf, seed):
     (on the host, under the reference's names)."""
     params, opt, key, metrics = compiled(params, opt, {"tokens": jnp.asarray(first)}, key)
     start = model.program_params(conf, seed)
-    norms = jax.jit(lambda p, s, mu: (reference._norms(canonical(mu)),
+    norms = jax.jit(lambda p, s, mu: (reference._norms(canonical(conf, mu)),
                                       reference._norms(jax.tree_util.tree_map(
-                                          jnp.subtract, canonical(p), canonical(s)))))
+                                          jnp.subtract, canonical(conf, p),
+                                          canonical(conf, s)))))
     moment, change = norms(params, start, opt.mu)
     out = {"loss": [float(v) for v in np.asarray(metrics["loss"])],
-           "moment_tree": jax.device_get(jax.jit(canonical)(opt.mu)),
+           "moment_tree": jax.device_get(jax.jit(lambda mu: canonical(conf, mu))(opt.mu)),
            "moment": {k: np.asarray(v, np.float64) for k, v in moment.items()},
            "change": {k: np.asarray(v, np.float64) for k, v in change.items()}}
     del start
