@@ -67,6 +67,9 @@ class Record:
     e2e: Dict[str, float] = dataclasses.field(default_factory=dict)
     counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     check: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # Printed beside a compared number, not compared: a resolved gap's
+    # plain reading and the router flips taken, say.
+    check_notes: Dict[str, dict] = dataclasses.field(default_factory=dict)
     attempted: int = 0
     failed: int = 0
     memory_peak_bytes: int = 0
@@ -89,12 +92,15 @@ class _Untraced:
 
 
 class _Traced:
-    """A profiler trace over [at, at + dur) of the window, bracketed by
-    program counters read (with a device sync) at both ends."""
+    """A profiler trace from the first poll at or after ``at`` of the
+    window, for ``dur`` seconds counted from that start (a tick that runs
+    past ``at`` moves the trace, it does not shorten it), or to the
+    window's end; bracketed by program counters read (with a device sync)
+    at both ends."""
 
     def __init__(self, cell: Cell, read_counters: Callable[[], dict], at: float, dur: float):
         self.cell, self.read = cell, read_counters
-        self.t_on, self.t_off = at, at + dur
+        self.t_on, self.dur, self.t_off = at, dur, math.inf
         self.state = "before"
         self.counters: dict = {}
         self.trace = self.window = None
@@ -110,6 +116,7 @@ class _Traced:
             jax.profiler.start_trace(self.dir, profiler_options=opts)
             self._span = annotate(TRACE_SPAN)
             self._span.__enter__()
+            self.t_off = elapsed + self.dur
             self.state = "on"
         elif self.state == "on" and elapsed >= self.t_off:
             self.close()
@@ -136,7 +143,8 @@ class _Traced:
 
 def profile_window(cell: Cell, read_counters: Callable[[], dict], at: float, dur: float):
     """The traced stretch of a ``--trace 1`` run (nothing otherwise):
-    from ``at`` of the window, for ``dur`` seconds at most."""
+    from ``at`` of the window, for ``dur`` seconds from its start (or to
+    the window's end)."""
     if not cell.trace:
         return _Untraced()
     return _Traced(cell, read_counters, at * cell.seconds, min(dur, 0.6 * cell.seconds))
@@ -220,7 +228,8 @@ def result_line(rec: Record, metrics: List[dict]) -> dict:
             "device_ops": trace_lib.top_ops(rec.trace, rec.window),
             "idle_gaps": trace_lib.idle_gaps(rec.trace, rec.window),
         }
-    check = {k: {"value": _finite(rec.check.get(k)), "limit": lim["limit"]}
+    check = {k: {"value": _finite(rec.check.get(k)), "limit": lim["limit"],
+                 **rec.check_notes.get(k, {})}
              for k, lim in cell.limits.items()}
     line["correct"] = bool(
         rec.attempted > 0 and rec.failed == 0 and check
@@ -270,6 +279,8 @@ def main(argv=None, *, t_process: float, root: Optional[Path] = None,
     rec = kind.run(cell)
     line = result_line(rec, cell_metrics(spec, workload["name"], cell.trace))
     for k, c in line["check"].items():
-        print(f"check {k} {c['value']!r} limit {c['limit']!r}", file=sys.stderr, flush=True)
+        notes = "".join(f" {n} {v!r}" for n, v in c.items() if n not in ("value", "limit"))
+        print(f"check {k} {c['value']!r} limit {c['limit']!r}{notes}", file=sys.stderr,
+              flush=True)
     print(json.dumps(line), flush=True)
     return 0

@@ -24,6 +24,17 @@ is then new files only.  Such a module provides:
   full causal sequences, written with ``bench/reference.py``'s ``mm``,
   ``ein``, ``rmsnorm`` and ``rope`` (so that the float8 control covers
   it), and optionally ``head_logits`` in place of the reference's;
+* optionally, for a layer with routed experts, two hooks that let the
+  check tell a router near-tie from a wrong program
+  (``reference._TieSearch``, used where the cell's limits file gives
+  ``served_gap.router_tie``): ``route_margins(x, w, conf, kind)``, for each
+  position of the layer's input (B, S, d), the selection-score gap
+  between the last chosen expert and the first unchosen one (B, S), or
+  ``None`` for a layer without a router; and a ``flips`` argument to
+  ``layer_forward`` (a (B, S) boolean mask, or ``None``): at the
+  positions it marks the layer takes the first unchosen expert in place
+  of the last chosen one, its combine weights formed as the published
+  router forms them;
 * ``param_count``, ``train_step_flops``, ``decode_steps`` and
   ``decode_attention``: the operations and bytes that the benchmark
   reads through ``bench/flops.py`` (which lists what else it may give).

@@ -15,7 +15,8 @@ module may pick by index), and optionally the head.  Shared here by every archit
 ``ein``, ``rmsnorm``, ``rope``) and its float8 control; the COMtune link
 (arXiv 2112.09407 Eq. 7, 8 and 12) and its Gilbert–Elliott channel; the
 loop over the layers with the link after ``split_after_layers``; the
-served-token gaps; the training loss, Adam and the per-leaf norms.
+served-token gaps, with router near-ties resolved where a cell asks
+(``_TieSearch``); the training loss, Adam and the per-leaf norms.
 
 ``prec="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 and every cotangent to e5m2, each with a per-tensor scale, the
@@ -280,7 +281,17 @@ def _keep_masks(keys, n, per_packet, shuffle, ge):
     return jax.vmap(jax.vmap(lambda k: ge_keep(k, n, per_packet, shuffle, ge)))(keys)
 
 
-def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False):
+def _served_at(r, length):
+    """Each position's served token and the mask of served positions."""
+    p, n = len(r["prompt"]), len(r["tokens"])
+    served = np.zeros((length,), np.int32)
+    served[p - 1: p - 1 + n] = r["tokens"]
+    pos = np.zeros((length,), bool)
+    pos[p - 1: p - 1 + n] = True
+    return served, pos
+
+
+def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False, gap=None):
     """Widest gaps over a sample of served requests.
 
     ``sample`` holds per request: ``prompt`` (P,), ``tokens`` (n,) served,
@@ -289,7 +300,12 @@ def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False):
     decode round j feeds ``tokens[j]`` at position P + j under
     ``round_keys[j]``.  Position P - 1 + j predicts ``tokens[j]``.
     Sequences are padded to ``shape`` = (rows, positions), the same for
-    every run of a cell, so that each program compiles once."""
+    every run of a cell, so that each program compiles once.
+
+    ``gap`` is the cell's ``served_gap`` entry of its limits file.  Where
+    it has ``router_tie``, router near-ties are resolved (``_TieSearch``)
+    and the plain gaps and the flips taken are returned beside the
+    resolved ones; without it no search runs."""
     m = dims(conf)
     pk = conf["link"]
     seqs = [np.concatenate([r["prompt"], r["tokens"][:-1]]) for r in sample]
@@ -305,18 +321,20 @@ def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False):
         keys[j, len(r["prompt"]): len(s)] = np.asarray(r["round_keys"])
     keep = _keep_masks(jnp.asarray(keys), m["d"], pk["elements_per_packet"],
                        pk["shuffle"], ge)
+    tie = (gap or {}).get("router_tie")
     with jax.default_matmul_precision("highest"):
+        if tie is not None:
+            x_ctl = (serve_hidden(conf, seed, tokens, keep, loss, "fp8")[0]
+                     if with_control else None)
+            return _TieSearch(conf, seed, tokens, keep, loss, gap["limit"], tie).run(
+                sample, x_ctl)
         x_ref, outer = serve_hidden(conf, seed, tokens, keep, loss, "f32")
         x_ctl = (serve_hidden(conf, seed, tokens, keep, loss, "fp8")[0]
                  if with_control else x_ref)
         ck = _conf_key(conf)
         worst = {"served": 0.0, "control": 0.0}
         for j, r in enumerate(sample):
-            p, n = len(r["prompt"]), len(r["tokens"])
-            served = np.zeros((length,), np.int32)
-            served[p - 1: p - 1 + n] = r["tokens"]
-            pos = np.zeros((length,), bool)
-            pos[p - 1: p - 1 + n] = True
+            served, pos = _served_at(r, length)
             g = _gaps(x_ref[j], x_ctl[j], outer, ck, with_control,
                       jnp.asarray(served), jnp.asarray(pos))
             for name, v in g.items():
@@ -324,6 +342,139 @@ def serve_gaps(conf, seed, sample, loss, ge, shape, with_control=False):
     if not with_control:
         worst.pop("control")
     return worst
+
+
+# ---------------------------------------------------------------------------
+# Serving: router near-ties
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _apply_layer_flipped(x, w, flips, conf_key, kind):
+    conf = json.loads(conf_key)
+    return model.arch(conf).layer_forward(x, w, conf, "f32", kind, flips=flips)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _route_margins(x, w, conf_key, kind):
+    conf = json.loads(conf_key)
+    return model.arch(conf).route_margins(x, w, conf, kind)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _row_gaps(x, outer, conf_key, picks):
+    """Per position of one row (1, L, d): how far the picked token's
+    reference logit lies below the reference's best."""
+    conf = json.loads(conf_key)
+    ref = _head(conf)(x, outer, conf, "f32")[0]
+    return jnp.max(ref, -1) - jnp.take_along_axis(ref, picks[:, None], -1)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _control_picks(x_ctl, outer, conf_key):
+    """The float8 control's first choice at each position of one row."""
+    conf = json.loads(conf_key)
+    return jnp.argmax(_head(conf)(x_ctl[None], outer, conf, "fp8")[0], -1).astype(jnp.int32)
+
+
+class _TieSearch:
+    """The f32 reference, one sampled row at a time, with router near-ties
+    resolved.
+
+    Where the served path (bfloat16) and the reference (float32) see two
+    experts' selection scores within rounding of each other, they may
+    choose differently; that is no error, but it moves the position's
+    logits as far as a wrong program would.  So at each served position,
+    in order, whose gap exceeds ``limit``: one flip (``layer_forward``'s
+    ``flips``: the first unchosen expert in place of the last chosen) is
+    tried at that position in each router layer whose margin
+    (``route_margins``) is under ``tie["tie_margin"]``, smallest first;
+    the first that brings the gap to or under the limit is kept in force
+    for the rest of the row.  At most ``tie["max_flips"]`` are kept over
+    the whole sample; a gap that no flip explains stays as it is."""
+
+    def __init__(self, conf, seed, tokens, keep, loss, limit, tie):
+        self.conf, self.seed, self.tokens, self.keep, self.loss = conf, seed, tokens, keep, loss
+        self.limit = float(limit)
+        self.margin, self.max_flips = float(tie["tie_margin"]), int(tie["max_flips"])
+        self.ck = _conf_key(conf)
+        if not hasattr(model.arch(conf), "route_margins"):
+            raise ValueError(f"{conf['name']}: router_tie is set, but the architecture "
+                             "gives no route_margins")
+        self.outer = outer_at(conf, seed)
+
+    def _run(self, j, start, x, flips, inputs, margins):
+        """Layers ``start``.. of row ``j`` from ``x``, the input of layer
+        ``start``; fills ``inputs`` and ``margins`` from there on and
+        returns the final hidden states (1, L, d)."""
+        m = dims(self.conf)
+        for i in range(start, m["layers"]):
+            if i == m["split"] and i > start:
+                x = serve_link(x, self.keep[j: j + 1], self.conf, self.loss)
+            w = layer_at(self.conf, self.seed, i)
+            kind = model.layer_kind(self.conf, i)
+            inputs[i] = x
+            mg = _route_margins(x, w, self.ck, kind)
+            margins[i] = None if mg is None else np.asarray(mg)
+            f = None if mg is None else jnp.asarray(flips[i])
+            x = _apply_layer_flipped(x, w, f, self.ck, kind)
+        return x
+
+    def _plain(self, j):
+        m = dims(self.conf)
+        x = jnp.take(self.outer["embed"], jnp.asarray(self.tokens[j: j + 1]), axis=0)
+        if m["split"] == 0:
+            x = serve_link(x, self.keep[j: j + 1], self.conf, self.loss)
+        inputs, margins = [None] * m["layers"], [None] * m["layers"]
+        flips = [np.zeros((1, self.tokens.shape[1]), bool)] * m["layers"]
+        final = self._run(j, 0, x, flips, inputs, margins)
+        return final, flips, inputs, margins
+
+    def _resolve(self, j, plain, picks, pos, budget):
+        """Plain and resolved gaps of row ``j`` for the tokens ``picks`` at
+        the positions ``pos``, and the flips kept (at most ``budget``)."""
+        final, flips, inputs, margins = plain
+        inputs, margins = list(inputs), list(margins)
+        picks = jnp.asarray(picks)
+        gaps = np.asarray(_row_gaps(final, self.outer, self.ck, picks))
+        first, kept = float(np.max(gaps, where=pos, initial=0.0)), 0
+        for p in np.flatnonzero(pos):
+            if gaps[p] <= self.limit:
+                continue
+            if kept >= budget:
+                break
+            near = sorted((mg[0, p], i) for i, mg in enumerate(margins)
+                          if mg is not None and mg[0, p] < self.margin)
+            for _, i in near:
+                f = list(flips)
+                f[i] = flips[i].copy()
+                f[i][0, p] = True
+                ins, mgs = list(inputs), list(margins)
+                x = self._run(j, i, inputs[i], f, ins, mgs)
+                g = np.asarray(_row_gaps(x, self.outer, self.ck, picks))
+                if g[p] <= self.limit:
+                    flips, inputs, margins, gaps, kept = f, ins, mgs, g, kept + 1
+                    break
+        return first, float(np.max(gaps, where=pos, initial=0.0)), kept
+
+    def run(self, sample, x_ctl):
+        length = self.tokens.shape[1]
+        out = {"served": 0.0, "served_plain": 0.0, "router_flips": 0}
+        if x_ctl is not None:
+            out.update(control=0.0, control_plain=0.0, control_flips=0)
+        for j, r in enumerate(sample):
+            plain = self._plain(j)
+            served, pos = _served_at(r, length)
+            todo = [("served", "router_flips", served)]
+            if x_ctl is not None:
+                todo.append(("control", "control_flips",
+                             _control_picks(x_ctl[j], self.outer, self.ck)))
+            for name, count, picks in todo:
+                first, resolved, kept = self._resolve(
+                    j, plain, picks, pos, self.max_flips - out[count])
+                out[name] = max(out[name], resolved)
+                out[name + "_plain"] = max(out[name + "_plain"], first)
+                out[count] += kept
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -148,9 +148,12 @@ def pick_sample(done: List[loadgen.Req], seed: int, check: dict) -> List[loadgen
     return out
 
 
-def check_sample(conf: dict, traffic: dict, seed: int, sample, keys, control=False) -> dict:
+def check_sample(conf: dict, traffic: dict, seed: int, sample, keys, control=False,
+                 gap=None) -> dict:
     """Widest gap of a served token below the reference's best logit over
-    the sample (and the control's, with ``control``)."""
+    the sample (and the control's, with ``control``).  ``gap`` is the
+    cell's ``served_gap`` limits entry; with ``router_tie`` in it, router
+    near-ties are resolved (``reference.serve_gaps``)."""
     link = traffic["link"]
     ge = reference.ge_params(link["loss_rate"], **link.get("channel_params", {}))
     pool = traffic["pool"]
@@ -160,7 +163,8 @@ def check_sample(conf: dict, traffic: dict, seed: int, sample, keys, control=Fal
     items = [{"prompt": r.prompt, "tokens": np.asarray(r.tokens), "prefill_key": pre[j],
               "round_keys": rnd[j][: len(r.tokens) - 1]} for j, r in enumerate(sample)]
     shape = (len(rows), pool["max_prompt"] + pool["max_new"])
-    return reference.serve_gaps(conf, seed, items, ge[-1], ge, shape, with_control=control)
+    return reference.serve_gaps(conf, seed, items, ge[-1], ge, shape, with_control=control,
+                                gap=gap)
 
 
 def _counters(engine) -> dict:
@@ -223,7 +227,12 @@ def run(cell) -> Record:
     del engine, params, win, traced
     gc.collect()
     if sample:
-        rec.check["served_gap"] = check_sample(conf, traffic, cell.seed, sample, keys)["served"]
+        gap = cell.limits.get("served_gap")
+        got = check_sample(conf, traffic, cell.seed, sample, keys, gap=gap)
+        rec.check["served_gap"] = got["served"]
+        if "router_flips" in got:
+            rec.check_notes["served_gap"] = {"plain": got["served_plain"],
+                                             "router_flips": got["router_flips"]}
     else:
         rec.check["served_gap"] = math.inf
     return rec

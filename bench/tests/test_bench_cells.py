@@ -14,7 +14,7 @@ import jax
 import pytest
 
 from bench import flops, harness, model, serve
-from tinycells import CELLS, ROOT, TINY, TRAFFIC
+from tinycells import CELLS, ROOT, ROUTER, ROUTER_TIE, TINY, TRAFFIC
 
 E2E = {"serve": {"output_tokens_per_s", "request_latency_p95_ms", "setup_s"},
        "train": {"train_tokens_per_s", "setup_s"}}
@@ -71,13 +71,15 @@ def test_new_cell_mix_and_metric_are_data(tiny_root, capsys):
     assert "breakdown" in line
 
 
-def _add_cell(root, conf_name, arch, cell, limit):
-    """A configuration naming ``arch`` and a serve cell of it under the
-    open mix, as files and entries only."""
+def _add_cell(root, conf_name, arch, cell, limit, tie=None, **keys):
+    """A configuration naming ``arch`` (with the further ``keys``) and a
+    serve cell of it under the open mix (with ``router_tie`` = ``tie``
+    where given), as files and entries only."""
     bench = root / "bench"
-    conf = dict(TINY, name=conf_name, source="test", architectures=[arch])
+    conf = dict(TINY, name=conf_name, source="test", architectures=[arch], **keys)
     (bench / "configs" / f"{conf_name}.json").write_text(json.dumps(conf))
-    (bench / "limits" / f"{cell}.json").write_text(json.dumps({"served_gap": {"limit": limit}}))
+    gap = {"limit": limit} if tie is None else {"limit": limit, "router_tie": tie}
+    (bench / "limits" / f"{cell}.json").write_text(json.dumps({"served_gap": gap}))
     spec = json.loads((root / "BENCHMARK.json").read_text())
     spec["configs"].append({"name": conf_name, "source": "test",
                             "file": f"bench/configs/{conf_name}.json", "reduced": [],
@@ -95,15 +97,21 @@ def _bench_files(root):
             for p in (root / "bench").rglob("*") if p.is_file() and "__pycache__" not in p.parts}
 
 
-# Made-up architectures, each a module under bench/tests and what its
-# program configuration must show: Qwen2 with no q, k or v biases; and
-# Qwen2 with two kinds of layer picked by index (a full-attention
-# prologue, then sliding-window layers).
+# Made-up architectures, each a module under bench/tests, what its
+# program configuration must show, and its further configuration keys and
+# router-tie settings: Qwen2 with no q, k or v biases; Qwen2 with two
+# kinds of layer picked by index (a full-attention prologue, then
+# sliding-window layers); and Qwen2 with routed experts, whose cell
+# resolves router near-ties.
 MADE_UP = {
-    "MadeUpNoBiasForCausalLM": ("nobias_arch.py", lambda cfg: cfg.qkv_bias is False),
+    "MadeUpNoBiasForCausalLM": ("nobias_arch.py", lambda cfg: cfg.qkv_bias is False, {}, None),
     "MadeUpWindowForCausalLM": ("window_arch.py",
                                 lambda cfg: len(cfg.prologue) == 1
-                                and cfg.unit_pattern[0].window == 8),
+                                and cfg.unit_pattern[0].window == 8, {}, None),
+    "MadeUpRouterForCausalLM": ("router_arch.py",
+                                lambda cfg: cfg.unit_pattern[0].moe
+                                and cfg.capacity_factor * cfg.top_k == cfg.num_experts,
+                                ROUTER, ROUTER_TIE),
 }
 
 
@@ -112,22 +120,23 @@ def test_new_architecture_is_files_only(tiny_root, capsys, monkeypatch, arch):
     """A made-up architecture, added as its module, a configuration, a
     cell and its limits, runs a serve cell to ``correct``; no file under
     ``bench/`` is edited, and the float8 control reads above the program
-    (and the cell's limit) on it."""
+    (and the cell's limit) on it, after any router near-ties are
+    resolved."""
     from repro.models import lm
 
-    fixture, shows = MADE_UP[arch]
+    fixture, shows, keys, tie = MADE_UP[arch]
     before = _bench_files(tiny_root)
     shutil.copy(Path(__file__).with_name(fixture), tiny_root / "bench" / "arch" / f"{arch}.py")
     limit = CELLS["tiny-serve-open"][2]["served_gap"]
-    _add_cell(tiny_root, "tiny-made-up", arch, "tiny-made-up-serve", limit)
+    _add_cell(tiny_root, "tiny-made-up", arch, "tiny-made-up-serve", limit, tie, **keys)
     after = _bench_files(tiny_root)
     assert all(after[p] == h for p, h in before.items())
 
     seen = {}
     real = serve.check_sample
 
-    def with_control(conf, traffic, seed, sample, keys, control=False):
-        seen.update(real(conf, traffic, seed, sample, keys, control=True))
+    def with_control(conf, traffic, seed, sample, keys, control=False, gap=None):
+        seen.update(real(conf, traffic, seed, sample, keys, control=True, gap=gap))
         return seen
 
     monkeypatch.setattr(serve, "check_sample", with_control)

@@ -97,8 +97,8 @@ def test_serve_control_fails_the_limit(tiny_root, capsys, monkeypatch):
     seen = {}
     real = serve.check_sample
 
-    def with_control(conf, traffic, seed, sample, keys, control=False):
-        seen.update(real(conf, traffic, seed, sample, keys, control=True))
+    def with_control(conf, traffic, seed, sample, keys, control=False, gap=None):
+        seen.update(real(conf, traffic, seed, sample, keys, control=True, gap=gap))
         return seen
 
     monkeypatch.setattr(serve, "check_sample", with_control)

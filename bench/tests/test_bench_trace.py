@@ -155,3 +155,31 @@ def test_peaks_table():
     assert peaks.bound_seconds(0.0, 819e9 * 2, "TPU v5 lite") == pytest.approx(2.0)
     with pytest.raises(KeyError):
         peaks.peaks("TPU v9 imaginary")
+
+
+@pytest.mark.parametrize("first_poll", [1.0, 4.5])
+def test_trace_runs_its_length_from_its_own_start(monkeypatch, first_poll):
+    """Under a fake clock: the trace starts at the first poll at or after
+    ``at`` and runs ``dur`` from there, also where one long tick put that
+    poll past ``at + dur``; the window's end closes it in any case."""
+    import jax
+
+    from bench import harness
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    reads = iter(range(100))
+    traced = harness._Traced(None, lambda: {"n": next(reads)}, at=1.0, dur=3.0)
+    traced.poll(0.5)
+    assert traced.state == "before"
+    traced.poll(first_poll)
+    assert traced.state == "on"
+    traced.poll(first_poll + 2.99)
+    assert traced.state == "on"
+    traced.poll(first_poll + 3.0)
+    assert traced.state == "done" and traced.counters == {"n": 1}
+
+    cut = harness._Traced(None, lambda: {"n": 0}, at=1.0, dur=3.0)
+    cut.poll(first_poll)
+    cut.close()                      # the window ends first
+    assert cut.state == "done"

@@ -18,6 +18,14 @@ TINY = {
              "clip": [-6.0, 6.0], "elements_per_packet": 25, "shuffle": True,
              "dropout_rate": 0.2},
 }
+# A routed-expert configuration's further keys (``router_arch.py``), and
+# its cells' router-tie settings: at these widths a bfloat16 router moves
+# the margin between the k-th and the (k+1)-th probability by 0.0035 at
+# most over 4096 random positions (0.0017 at the 99th percentile); the
+# tie margin is twice that.
+ROUTER = {"num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 32}
+ROUTER_TIE = {"tie_margin": 0.007, "max_flips": 2,
+              "from": "CPU: twice the largest bf16 shift of the margin at these widths"}
 TRAFFIC = {
     "tiny-open": {
         "kind": "serve", "arrivals": {"process": "poisson", "rate_per_s": 40.0},
