@@ -38,7 +38,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import ARCHITECTURES
-from repro.kernels.decode_attention import decode_attention, decode_block_kv
+from repro.kernels.decode_attention import decode_attention
 from repro.launch.compile_cache import setup_compile_cache
 from repro.models import cache as cache_lib, lm
 from repro.models.attention import _naive_attn, _read_cache
@@ -90,7 +90,7 @@ def _time_step(fn, args, reps: int, rounds: int) -> float:
 def _layer_read_bytes(c, kvh, hd, kv_dtype, valid, block_kv, masked):
     itemsize = 1 if kv_dtype == "int8" else 4
     if masked:
-        bkv = decode_block_kv(c, block_kv)
+        bkv = min(block_kv, c)          # decode_attention's block_kv=
         rows = min(-(-min(valid, c) // bkv) * bkv, c)
     else:
         rows = c

@@ -118,8 +118,6 @@ class ModelConfig:
     attn_impl: str = "blockwise"
     attn_block_q: int = 512
     attn_block_kv: int = 1024
-    attn_decode_block_kv: int = 64  # KV block of the masked decode walk —
-                                    # decode reads ceil(valid/this) blocks
     scan_chunk: int = 256           # mamba/mlstm chunked-scan length
 
     # ----- derived -----

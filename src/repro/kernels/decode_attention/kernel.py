@@ -4,11 +4,14 @@ attention for the s == 1 decode step, with inline int8 dequantization.
 Each wrapper reads the model's cache layout as it is, lane-dense:
 
 * q        — (B, KV, G, hd)   one query token, GQA-grouped
-* k / v    — (B, C, KV*hd)    rotating cache buffer (int8 codes or bf16),
-                              one row of KV*hd lanes per position (a
-                              (B, C, KV, hd) buffer is reshaped to it)
-* k/v scale— (B, C, KV)       per-(pos, head) bf16 absmax scales (int8 only)
-* n_valid  — (B, 1) int32     count of live cache slots for this request
+* k / v    — (B, C, KV*hd)    one layer's rotating cache buffer (int8 codes
+                              or bf16), one row of KV*hd lanes per position
+                              (a (B, C, KV, hd) buffer is reshaped to it),
+             or (U, B, C, KV*hd) the layer's cache stacked over the stack's
+                              units, read at unit ``unit`` in place
+* k/v scale— (B, C, KV)       per-(pos, head) bf16 absmax scales (int8 only),
+             or (U, B, C, KV) stacked like k / v
+* n_valid  — (B,) int32       count of live cache slots for this request
 
 TPU tiles the last two dims of every VMEM block by (8, 128), so a block
 that picks one KV head out of ``(C, KV, hd)`` is refused.  Here the
@@ -24,24 +27,41 @@ its own head only, and ``p @ v`` leaves head j's output in head j's lanes
 are spread onto the lanes by a ``(bkv, KV) @ (KV, lanes)`` 0/1 selector
 matmul at full precision, which copies each scale exactly.
 
-Contiguous kernel: grid (B, head blocks); the body walks KV blocks with a
-``fori_loop`` whose upper bound is ``ceil(n_valid / block_kv)`` (``n_valid``
-in SMEM) — blocks past the valid prefix are never computed on or
-dequantized.  HBM traffic: each grid step's BlockSpec DMAs the whole
-``(C, hpb*hd)`` K and V panels of its head block, so the contiguous kernel
-reads the full cache (O(max_seq) bytes); the scale block ``(C, KV)`` keeps
-its index across a request's head blocks and is fetched once per request.
-Paged kernel: reads only the walked blocks (see below).
+Both kernels walk KV blocks on the minor grid axis with one body
+(:func:`_make_walk_kernel`): grid (requests, blocks), each step delivers
+one ``(block, KV*hd)`` K and V block across all heads (plus its
+``(block, KV)`` scales), walks the head blocks with static 128-aligned
+lane slices, and keeps the softmax state of every head block in VMEM
+scratch across the sequential block axis.  The index maps read
+``n_valid`` from SMEM (scalar prefetch) and clamp the block position to
+the request's last valid block, so steps past ``ceil(n_valid / block)``
+repeat the previous block index: Pallas issues no DMA for them and the
+body skips them.  Both kernels read O(valid) bytes.
+
+* **Contiguous** (``flash_decode_kernel``): the cache is a pool of
+  *panels*, ``(N, C, KV*hd)`` — a stacked ``(U, B, C, KV*hd)`` cache is
+  the pool of its ``U*B`` panels by a free reshape — and a second
+  scalar-prefetch vector names each request's panel (``unit*B + b``).  So
+  the kernel reads unit ``unit`` of a stacked cache where it lies; nothing
+  copies the layer out first.  Its batching rule (``custom_vmap``) folds a
+  vmapped axis into the request axis and offsets the panel indices, so
+  the slot-pool engine's vmap binds ONE ``pallas_call`` over all slots
+  per layer, with the per-slot ``n_valid``, never a loop of per-slot
+  calls (which is what ``pallas_call`` does with a batched scalar-prefetch
+  operand).  The block size comes from the cache's shape alone
+  (:func:`decode_block_kv`).
+* **Paged** (``paged_flash_decode_kernel``): the walk follows a
+  per-request block table over a shared ``(num_blocks, block_size,
+  KV*hd)`` pool; the table is the second scalar-prefetch operand.
 
 Rotating sliding-window caches need no extra handling: writes land at
 ``index % C`` (``models.attention._write_decode``), so the live slots are
 always the contiguous prefix ``[0, min(index + 1, C))``.  Cached keys carry
 RoPE from write time and softmax is permutation-invariant over slots.
 
-The contiguous kernel is vmap-able (the slot-pool engine vmaps it over the
-slot axis with a per-slot ``n_valid``); ``ref.py`` runs the same f32
-arithmetic per head, so the jnp fallback agrees with the interpret-mode
-kernel to float-ulp level (XLA summation order is the only difference).
+``ref.py`` runs the same f32 arithmetic per head, so the jnp fallback
+agrees with the interpret-mode kernels to float-ulp level (XLA summation
+order is the only difference).
 """
 
 from __future__ import annotations
@@ -58,6 +78,29 @@ from repro.kernels.runtime import pallas_interpret
 
 NEG_INF = -1.0e30
 LANES = 128
+# Bytes of one K (or V) block the contiguous walk aims for: large enough
+# that a grid step's fixed cost is small beside its DMA, small enough that
+# a slot's last, partly valid block over-reads little.
+BLOCK_BYTES = 128 * 1024
+
+
+def decode_block_kv(cache_len: int, lanes: int, itemsize: int) -> int:
+    """KV rows per block of the decode walk, from the cache's shape alone:
+    ``cache_len`` rows of ``lanes`` elements of ``itemsize`` bytes.
+
+    Aims at :data:`BLOCK_BYTES` per K block (64 rows of 1024 bf16 lanes,
+    128 rows of 512).  A cache that fits in one such block is one block
+    (a block spanning the whole dim is always legal).  Otherwise the
+    largest divisor of ``cache_len`` under the aim that is a whole number
+    of the dtype's packed (8, 128) tiles; when there is none, the aim
+    itself, and ``decode_attention`` pads the position axis to a multiple.
+    """
+    rows = max(BLOCK_BYTES // (lanes * itemsize), 1)
+    if cache_len <= rows:
+        return cache_len
+    tile = 32 // min(itemsize, 4)                 # f32 8, bf16 16, int8 32
+    fits = [b for b in range(tile, rows + 1, tile) if cache_len % b == 0]
+    return fits[-1] if fits else max(rows // tile, 1) * tile
 
 
 def _heads_per_block(kvh: int, hd: int) -> int:
@@ -129,116 +172,26 @@ def _attend(q, k, v, pos0, n_valid, carry, *, softcap: float, scale):
     return acc * corr + pv, m_new, l_new
 
 
-def _make_kernel(*, block_kv, softcap, quantized, kvh, hpb, hd):
-    def kernel(*refs):
-        if quantized:
-            n_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref = refs
-        else:
-            n_ref, q_ref, k_ref, v_ref, o_ref = refs
-        q = q_ref[0, 0].astype(jnp.float32)                  # (R, L)
-        scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-        n_valid = n_ref[0, 0, 0]
-        n_blocks = (n_valid + block_kv - 1) // block_kv
-        if quantized:
-            sel = _lane_selector(kvh, hpb, hd, pl.program_id(1))
-
-        def body(kj, carry):
-            start = pl.multiple_of(kj * block_kv, block_kv)
-            sl = pl.ds(start, block_kv)
-            if quantized:
-                k = _dequant(k_ref[0, sl, :], ks_ref[0, sl, :], sel)
-                v = _dequant(v_ref[0, sl, :], vs_ref[0, sl, :], sel)
-            else:
-                k = k_ref[0, sl, :].astype(jnp.float32)      # (bkv, L)
-                v = v_ref[0, sl, :].astype(jnp.float32)
-            return _attend(q, k, v, start, n_valid, carry,
-                           softcap=softcap, scale=scale)
-
-        r, lw = q.shape
-        init = (jnp.zeros((r, lw), jnp.float32),
-                jnp.full((r, 1), NEG_INF, jnp.float32),
-                jnp.zeros((r, 1), jnp.float32))
-        acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_kv", "softcap", "interpret")
-)
-def flash_decode_kernel(
-    q: jax.Array,                        # (B, KV, G, hd)
-    k: jax.Array,                        # (B, C, KV*hd) or (B, C, KV, hd)
-    v: jax.Array,
-    k_scale: Optional[jax.Array],        # (B, C, KV) or None
-    v_scale: Optional[jax.Array],
-    n_valid: jax.Array,                  # (B, 1) int32
-    *,
-    block_kv: int = 64,
-    softcap: float = 0.0,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    b, kvh, g, hd = q.shape
-    c = k.shape[1]
-    assert c % block_kv == 0, (c, block_kv)
-    quantized = k_scale is not None
-    hpb = _heads_per_block(kvh, hd)
-    nhb, r, lw = kvh // hpb, hpb * g, hpb * hd
-    in_specs = [
-        pl.BlockSpec((1, 1, 1), lambda i, hb: (i, 0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, r, lw), lambda i, hb: (i, hb, 0, 0)),
-        pl.BlockSpec((1, c, lw), lambda i, hb: (i, 0, hb)),
-        pl.BlockSpec((1, c, lw), lambda i, hb: (i, 0, hb)),
-    ]
-    args = [jnp.asarray(n_valid, jnp.int32).reshape(b, 1, 1), _expand_q(q, hpb),
-            k.reshape(b, c, kvh * hd), v.reshape(b, c, kvh * hd)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, c, kvh), lambda i, hb: (i, 0, 0))] * 2
-        args += [k_scale, v_scale]
-    out = pl.pallas_call(
-        _make_kernel(block_kv=block_kv, softcap=softcap, quantized=quantized,
-                     kvh=kvh, hpb=hpb, hd=hd),
-        grid=(b, nhb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, r, lw), lambda i, hb: (i, hb, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
-        interpret=pallas_interpret(interpret),
-        name="flash_decode_kernel",
-    )(*args)
-    return _collapse_out(out, hpb, g, hd)
-
-
 # ---------------------------------------------------------------------------
-# Paged variant: block-table walk with scalar-prefetch (SMEM) metadata
+# The block walk both kernels share
 # ---------------------------------------------------------------------------
 #
-# Same online-softmax arithmetic, different iteration structure: the KV
-# walk moves from a fori_loop inside one grid step to the (sequential,
-# minor) grid dimension j, because with a PrefetchScalarGridSpec it is the
-# *index map* — evaluated from SMEM-resident scalars before the DMA — that
-# picks which physical block to deliver.  Each step delivers one whole
-# pool block across all heads, ``(block_size, KV*hd)``, and walks the head
-# blocks with static 128-aligned lane slices; softmax state for every head
-# block persists across j in VMEM scratch.  The index map clamps j to the
-# last valid block, so steps past the valid prefix repeat the previous
-# block index and Pallas issues no DMA for them: the paged kernel reads
-# O(valid) bytes.  ``pl.when`` guards init (j == 0), the masked walk
-# (j * block_size < n_valid) and the final normalize/write (last j).
+# ``pl.when`` guards init (j == 0), the masked update (j * block <
+# n_valid) and the final normalize/write (last j).  The second
+# scalar-prefetch operand (panel ids or the block table) is consumed by
+# the index maps, not the body.
 
 
-def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
+def _make_walk_kernel(*, block, softcap, quantized, kvh, hpb, hd):
     nhb, lw = kvh // hpb, hpb * hd
 
     def kernel(*refs):
         if quantized:
-            (nv_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+            (nv_ref, _, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
              acc_ref, m_ref, l_ref) = refs
         else:
-            (nv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
+            (nv_ref, _, q_ref, k_ref, v_ref, o_ref,
              acc_ref, m_ref, l_ref) = refs
-        del bt_ref  # consumed by the index maps, not the body
         i = pl.program_id(0)
         j = pl.program_id(1)
         n_valid = nv_ref[i]
@@ -249,7 +202,7 @@ def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        @pl.when(j * block_size < n_valid)
+        @pl.when(j * block < n_valid)
         def _block():
             scale = 1.0 / jnp.sqrt(jnp.float32(hd))
             for hb in range(nhb):
@@ -263,7 +216,7 @@ def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
                     v = v_ref[0, :, lanes].astype(jnp.float32)
                 acc, m, l = _attend(
                     q_ref[0, hb].astype(jnp.float32), k, v,
-                    j * block_size, n_valid,
+                    j * block, n_valid,
                     (acc_ref[hb], m_ref[hb], l_ref[hb]),
                     softcap=softcap, scale=scale,
                 )
@@ -278,6 +231,134 @@ def _make_paged_kernel(*, block_size, softcap, quantized, kvh, hpb, hd):
             ).astype(o_ref.dtype)
 
     return kernel
+
+
+def _walk_call(qx, k, v, scales, n_valid, index, kv_map, *, n_blocks, block,
+               softcap, kvh, hpb, hd, name, interpret):
+    """One ``pallas_call`` of the block walk over ``n_blocks`` blocks per
+    request.  ``qx`` is the block-diagonal query ``(R, nhb, r, lw)``; K/V
+    (and the scales) are addressed through ``kv_map(i, j, n_valid, index)``
+    with blocks of ``block`` rows across all lanes."""
+    n_req, nhb, r, lw = qx.shape
+    lanes = k.shape[-1]
+    per_req = lambda i, j, nv, ix: (i, 0, 0, 0)
+    in_specs = [pl.BlockSpec((1, nhb, r, lw), per_req),
+                pl.BlockSpec((1, block, lanes), kv_map),
+                pl.BlockSpec((1, block, lanes), kv_map)]
+    in_specs += [pl.BlockSpec((1, block, kvh), kv_map)] * len(scales)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_req, n_blocks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, nhb, r, lw), per_req),
+        scratch_shapes=[
+            pltpu.VMEM((nhb, r, lw), jnp.float32),           # acc
+            pltpu.VMEM((nhb, r, 1), jnp.float32),            # m
+            pltpu.VMEM((nhb, r, 1), jnp.float32),            # l
+        ],
+    )
+    return pl.pallas_call(
+        _make_walk_kernel(block=block, softcap=softcap, quantized=bool(scales),
+                          kvh=kvh, hpb=hpb, hd=hd),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_req, nhb, r, lw), qx.dtype),
+        interpret=pallas_interpret(interpret),
+        name=name,
+    )(n_valid, index, qx, k, v, *scales)
+
+
+def _last_block(n_valid, block):
+    return jnp.maximum(n_valid - 1, 0) // block
+
+
+# ---------------------------------------------------------------------------
+# Contiguous kernel: a pool of panels, read in place
+# ---------------------------------------------------------------------------
+
+
+def _panel_walk(block: int, softcap: float, kvh: int, hpb: int, hd: int,
+                interpret: Optional[bool]):
+    """The contiguous walk as a ``custom_vmap`` function of
+    ``(qx (R, nhb, r, lw), k/v (N, C, L), scales, n_valid (R,), panel (R,))``.
+    Its batching rule folds the vmapped axis into the request axis (and,
+    where the pool is batched, into the panel axis, offsetting the panel
+    ids), then calls the function itself: vmapped at any depth it stays
+    one ``pallas_call``."""
+
+    @jax.custom_batching.custom_vmap
+    def walk(qx, k, v, scales, n_valid, panel):
+        block_at = lambda i, j, nv, pn: (
+            pn[i], jnp.minimum(j, _last_block(nv[i], block)), 0
+        )
+        return _walk_call(
+            qx, k, v, scales, n_valid, panel, block_at,
+            n_blocks=k.shape[1] // block, block=block, softcap=softcap,
+            kvh=kvh, hpb=hpb, hd=hd, name="flash_decode_kernel",
+            interpret=interpret,
+        )
+
+    @walk.def_vmap
+    def _fold(size, batched, qx, k, v, scales, n_valid, panel):
+        qb, kb, vb, sb, nb, pb = batched
+        lead = lambda x, b: x if b else jnp.broadcast_to(x, (size,) + x.shape)
+        fold = lambda x: x.reshape((-1,) + x.shape[2:])
+        offset = 0
+        if kb or vb or any(sb):
+            n_panels = k.shape[int(kb)]
+            offset = n_panels * jnp.arange(size, dtype=jnp.int32)[:, None]
+            k, v = fold(lead(k, kb)), fold(lead(v, vb))
+            scales = tuple(fold(lead(s, b)) for s, b in zip(scales, sb))
+        out = walk(fold(lead(qx, qb)), k, v, scales, fold(lead(n_valid, nb)),
+                   fold(lead(panel, pb) + offset))
+        return out.reshape((size, -1) + out.shape[1:]), True
+
+    return walk
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_kv", "softcap", "interpret")
+)
+def flash_decode_kernel(
+    q: jax.Array,                        # (B, KV, G, hd)
+    k: jax.Array,                        # (B, C, KV*hd) | (B, C, KV, hd)
+    v: jax.Array,                        #   or (U, B, C, KV*hd) with unit
+    k_scale: Optional[jax.Array],        # (B, C, KV) | (U, B, C, KV) | None
+    v_scale: Optional[jax.Array],
+    n_valid: jax.Array,                  # (B,) or (B, 1) int32
+    unit=None,                           # unit of a stacked cache, or None
+    *,
+    block_kv: Optional[int] = None,
+    softcap: float = 0.0,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Length-masked decode attention over one layer's cache, or over unit
+    ``unit`` of the layer's cache stacked over units, read in place.
+    ``block_kv`` defaults to :func:`decode_block_kv` of the cache's shape
+    and must divide the cache length."""
+    b, kvh, g, hd = q.shape
+    lanes = kvh * hd
+    if unit is None:                     # one layer: a stack of one unit
+        unit = 0
+        k, v = k.reshape(1, b, -1, lanes), v.reshape(1, b, -1, lanes)
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    u, c = k.shape[0], k.shape[2]
+    bkv = block_kv or decode_block_kv(c, lanes, k.dtype.itemsize)
+    assert c % bkv == 0, (c, bkv)
+    hpb = _heads_per_block(kvh, hd)
+    panels = lambda a: a.reshape((u * b,) + a.shape[2:])
+    scales = () if k_scale is None else (panels(k_scale), panels(v_scale))
+    panel = jnp.asarray(unit, jnp.int32) * b + jnp.arange(b, dtype=jnp.int32)
+    n = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32).reshape(-1), (b,))
+    out = _panel_walk(bkv, softcap, kvh, hpb, hd, interpret)(
+        _expand_q(q, hpb), panels(k), panels(v), scales, n, panel
+    )
+    return _collapse_out(out, hpb, g, hd)
+
+
+# ---------------------------------------------------------------------------
+# Paged kernel: block-table walk over a shared pool
+# ---------------------------------------------------------------------------
 
 
 @functools.partial(
@@ -299,45 +380,17 @@ def paged_flash_decode_kernel(
     b, kvh, g, hd = q.shape
     n_phys, bs = k.shape[:2]
     assert bs == block_size, (bs, block_size)
-    j_l = block_table.shape[1]
-    quantized = k_scale is not None
     hpb = _heads_per_block(kvh, hd)
-    nhb, r, lw = kvh // hpb, hpb * g, hpb * hd
-
-    def block_at(i, j, nv, bt):
-        last = jnp.maximum(nv[i] - 1, 0) // block_size
-        return bt[i, jnp.minimum(j, last)]
-
-    kv_map = lambda i, j, nv, bt: (block_at(i, j, nv, bt), 0, 0)
-    per_req = lambda i, j, nv, bt: (i, 0, 0, 0)
-    in_specs = [
-        pl.BlockSpec((1, nhb, r, lw), per_req),
-        pl.BlockSpec((1, bs, kvh * hd), kv_map),
-        pl.BlockSpec((1, bs, kvh * hd), kv_map),
-    ]
-    args = [_expand_q(q, hpb), k.reshape(n_phys, bs, kvh * hd),
-            v.reshape(n_phys, bs, kvh * hd)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, bs, kvh), kv_map)] * 2
-        args += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, j_l),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nhb, r, lw), per_req),
-        scratch_shapes=[
-            pltpu.VMEM((nhb, r, lw), jnp.float32),           # acc
-            pltpu.VMEM((nhb, r, 1), jnp.float32),            # m
-            pltpu.VMEM((nhb, r, 1), jnp.float32),            # l
-        ],
+    rows = lambda a: a.reshape(n_phys, bs, kvh * hd)
+    scales = () if k_scale is None else (k_scale, v_scale)
+    block_at = lambda i, j, nv, bt: (
+        bt[i, jnp.minimum(j, _last_block(nv[i], block_size))], 0, 0
     )
-    out = pl.pallas_call(
-        _make_paged_kernel(block_size=block_size, softcap=softcap,
-                           quantized=quantized, kvh=kvh, hpb=hpb, hd=hd),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nhb, r, lw), q.dtype),
-        interpret=pallas_interpret(interpret),
-        name="paged_flash_decode_kernel",
-    )(jnp.asarray(n_valid, jnp.int32), jnp.asarray(block_table, jnp.int32),
-      *args)
+    out = _walk_call(
+        _expand_q(q, hpb), rows(k), rows(v), scales,
+        jnp.asarray(n_valid, jnp.int32), jnp.asarray(block_table, jnp.int32),
+        block_at, n_blocks=block_table.shape[1], block=block_size,
+        softcap=softcap, kvh=kvh, hpb=hpb, hd=hd,
+        name="paged_flash_decode_kernel", interpret=interpret,
+    )
     return _collapse_out(out, hpb, g, hd)

@@ -4,8 +4,10 @@ dispatch (Pallas kernel on accelerators, bit-identical jnp fallback on CPU).
 ``decode_attention`` consumes the model's decode state directly — the
 grouped query ``(B, 1, KV, G, hd)`` and the rotating cache dict in its
 native layout, rows of ``KV * hd`` lanes ``(B, C, KV * hd)`` (a
-``(B, C, KV, hd)`` cache is taken too), int8 codes + scales or bf16 — so
-no transposed/dequantized copy of the cache is ever materialized.  Dispatch:
+``(B, C, KV, hd)`` cache is taken too), or the layer's cache stacked over
+the stack's units ``(U, B, C, KV * hd)`` with the unit to read, int8
+codes + scales or bf16 — so no transposed, dequantized or per-layer copy
+of the cache is ever materialized.  Dispatch:
 
 * ``REPRO_FLASH_DECODE_IMPL=kernel|ref`` forces a path (tests/benchmarks);
 * otherwise the jnp fallback on CPU (a compiled interpret-mode Pallas call
@@ -15,12 +17,12 @@ no transposed/dequantized copy of the cache is ever materialized.  Dispatch:
 
 Both paths are vmap-able over a leading slot axis with per-slot
 ``n_valid`` — this is how the continuous-batching engine's fused decode
-step runs one length-masked attention per in-flight request.
+step runs one length-masked attention per in-flight request; the kernel
+path stays one ``pallas_call`` over every slot under that vmap.
 """
 
 from __future__ import annotations
 
-import math
 import os
 from typing import Any, Dict, Optional
 
@@ -28,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import (
+    decode_block_kv,
     flash_decode_kernel,
     paged_flash_decode_kernel,
 )
@@ -52,22 +55,6 @@ def _impl(override: Optional[str] = None) -> str:
     return "ref" if jax.default_backend() == "cpu" else "kernel"
 
 
-def decode_block_kv(cache_len: int, block_kv: int) -> int:
-    """Effective KV block of the masked walk.
-
-    Prefers the largest common divisor of ``cache_len`` and ``block_kv``
-    so the walk needs no copies (engine cache lengths are multiples of
-    the bucket floor, making this ``min(block_kv, cache_len)`` or a near
-    power of two).  When the divisor degenerates below 16 (coprime-ish
-    lengths like 65 or 100, where a gcd-sized walk would be slower than
-    the matvec it replaces), keeps ``block_kv`` — ``decode_attention``
-    then zero-pads the cache to a block multiple once per call instead.
-    """
-    bkv = min(block_kv, cache_len)
-    g = math.gcd(bkv, cache_len)
-    return g if g >= min(16, bkv) else bkv
-
-
 def _heads(x: jax.Array, kvh: int) -> jax.Array:
     """Cache rows ``(B, C, KV * hd)`` as ``(B, C, KV, hd)``: the jnp
     fallback walks one head at a time."""
@@ -79,50 +66,62 @@ def decode_attention(
     cache: Dict[str, Any],               # k/v (B, C, KV*hd) [+ k/v_scale]
     n_valid: jax.Array,                  # scalar or (B,) live-slot count
     *,
+    unit=None,
     softcap: float = 0.0,
-    block_kv: int = 64,
+    block_kv: Optional[int] = None,
     impl: Optional[str] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Length-masked decode attention over the rotating cache.
 
     Returns ``(B, 1, KV, G, hd)`` in ``q.dtype`` — a drop-in for the
-    decode branch of ``models.attention.attention_forward``.  Only cache
-    blocks below ``ceil(n_valid / block_kv)`` are read (and, for int8
-    caches, dequantized — inline, per block, in f32).
+    decode branch of ``models.attention.attention_forward``.  With
+    ``unit`` given, ``cache`` is the layer's cache stacked over the stack's
+    units, ``(U, B, C, KV*hd)``, and unit ``unit`` of it is attended where
+    it lies; a cache without ``unit`` is a stack of one.  Only cache blocks
+    below ``ceil(n_valid / block)`` are read (and, for int8 caches,
+    dequantized — inline, per block, in f32).  The block is
+    :func:`decode_block_kv` of the cache's shape; ``block_kv`` overrides it
+    (tests walk tiny caches in several blocks so).
     """
     b, s, kvh, g, hd = q.shape
     assert s == 1, f"decode attention is the s == 1 path, got S={s}"
     k, v = cache["k"], cache["v"]
     k_scale = cache.get("k_scale")
     v_scale = cache.get("v_scale")
-    c = k.shape[1]
-    bkv = decode_block_kv(c, block_kv)
+    if unit is None:
+        unit = 0
+        k, v = k.reshape(1, b, -1, kvh * hd), v.reshape(1, b, -1, kvh * hd)
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    c = k.shape[2]
+    bkv = min(block_kv or decode_block_kv(c, kvh * hd, k.dtype.itemsize), c)
     pad = (-c) % bkv
     if pad:
-        # Degenerate cache length (no usable divisor): pad the position
-        # axis to a block multiple.  Padded rows sit at k_pos >= C >=
-        # n_valid, so the validity mask never reads them; the one-copy
-        # cost only triggers for lengths the engines never produce.
+        # No divisor of the cache length is a whole number of tiles: pad
+        # the position axis to a block multiple.  Padded rows sit at
+        # k_pos >= C >= n_valid, so the validity mask never reads them;
+        # the copy only happens for lengths the engines never produce.
         grow = lambda a: jnp.pad(
-            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)
+            a, ((0, 0), (0, 0), (0, pad), (0, 0))
         )
         k, v = grow(k), grow(v)
         if k_scale is not None:
             k_scale, v_scale = grow(k_scale), grow(v_scale)
-    n = jnp.broadcast_to(
-        jnp.asarray(n_valid, jnp.int32).reshape(-1), (b,)
-    ).reshape(b, 1)
+    n = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32).reshape(-1), (b,))
     qh = q[:, 0]                                             # (B, KV, G, hd)
     if _impl(impl) == "kernel":
         out = flash_decode_kernel(
-            qh, k, v, k_scale, v_scale, n,
+            qh, k, v, k_scale, v_scale, n, unit,
             block_kv=bkv, softcap=softcap, interpret=interpret,
         )
     else:
+        at = lambda a: None if a is None else jax.lax.dynamic_index_in_dim(
+            a, unit, keepdims=False
+        )
         out = flash_decode_ref(
-            qh, _heads(k, kvh), _heads(v, kvh), k_scale, v_scale, n,
-            block_kv=bkv, softcap=softcap,
+            qh, _heads(at(k), kvh), _heads(at(v), kvh), at(k_scale),
+            at(v_scale), n[:, None], block_kv=bkv, softcap=softcap,
         )
     return out[:, None]
 

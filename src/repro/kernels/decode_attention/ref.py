@@ -2,9 +2,9 @@
 
 This is NOT a naive oracle: it runs ``kernel.py``'s arithmetic per head
 (same f32 dequant, same ``preferred_element_type``, same mask/where
-order, same online-softmax update expressions, same ``fori_loop`` bound
-``ceil(n_valid / block_kv)``) — the kernel batches a head block into one
-block-diagonal matmul whose extra terms are exact zeros — so CPU CI
+order, same online-softmax update expressions, same walk of
+``ceil(n_valid / block_kv)`` blocks) — the kernel batches a head block
+into one block-diagonal matmul whose extra terms are exact zeros — so CPU CI
 exercises the same arithmetic recipe the accelerator kernel runs, at the
 kernel's O(valid) cost: the traced loop bound lowers to a
 ``while_loop``, so blocks past the valid prefix are never read or

@@ -494,24 +494,23 @@ def attention_forward(
     elif cache is not None and s == 1:
         # ---- decode: write one slot, attend over the rotating buffer ----
         new_cache = _write_decode(cache, k, v, cache_index, unit)
-        layer = layer_of(new_cache, unit)
         if cfg.attn_impl in ("flash_decode", "blockwise"):
             # Length-masked flash decode: O(valid) cache blocks read,
-            # int8 KV dequantized inline — the serve engines' default.
+            # int8 KV dequantized inline, a stacked cache read at its
+            # unit in place — the serve engines' default.
             from repro.kernels.decode_attention import decode_attention
 
-            c = layer["k"].shape[1]
+            c = new_cache["k"].shape[-2]
             n_valid = jnp.minimum(
                 jnp.asarray(cache_index, jnp.int32) + 1, c
             )
             out = decode_attention(
-                qg, layer, n_valid,
-                softcap=cfg.logit_softcap,
-                block_kv=cfg.attn_decode_block_kv,
+                qg, new_cache, n_valid, unit=unit, softcap=cfg.logit_softcap,
             )
         else:
             out = _masked_decode_attn(
-                qg, layer, cache_index, cfg.logit_softcap, k.dtype
+                qg, layer_of(new_cache, unit), cache_index, cfg.logit_softcap,
+                k.dtype,
             )
     else:
         # ---- train / prefill: self-attention over the fresh sequence ----

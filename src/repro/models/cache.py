@@ -252,15 +252,28 @@ def write_prompt_blocks(
     return {"prologue": prologue, "units": units}
 
 
+def _kv_itemsize(cfg: ModelConfig) -> int:
+    if cfg.kv_cache_dtype == "int8":
+        return 1
+    return jnp.dtype(dtype_of(cfg.dtype)).itemsize
+
+
 def _attn_row_bytes(cfg: ModelConfig) -> int:
     """Bytes per KV row of one attention layer (k + v codes, plus bf16
     scales when the cache is int8-quantized)."""
-    dtype = dtype_of(cfg.dtype)
-    kv_itemsize = 1 if cfg.kv_cache_dtype == "int8" else jnp.dtype(dtype).itemsize
-    row_bytes = 2 * cfg.num_kv_heads * cfg.resolved_head_dim * kv_itemsize
+    row_bytes = 2 * cfg.num_kv_heads * cfg.resolved_head_dim * _kv_itemsize(cfg)
     if cfg.kv_cache_dtype == "int8":
         row_bytes += 2 * cfg.num_kv_heads * 2
     return row_bytes
+
+
+def _decode_block(cfg: ModelConfig, length: int) -> int:
+    """KV rows per block the contiguous decode kernel walks in one layer
+    of ``length`` cache rows."""
+    from repro.kernels.decode_attention import decode_block_kv
+
+    lanes = cfg.num_kv_heads * cfg.resolved_head_dim
+    return decode_block_kv(length, lanes, _kv_itemsize(cfg))
 
 
 def decode_read_bytes(
@@ -275,21 +288,18 @@ def decode_read_bytes(
 
     ``masked=False`` is the legacy full-cache path: every attention layer
     reads (and for int8, dequantizes) all ``cache_len`` K/V rows + scales.
-    ``masked=True`` is the length-masked flash-decode path: only
-    ``ceil(valid / attn_decode_block_kv)`` blocks are touched.
+    ``masked=True`` is the length-masked flash-decode path: the kernel's
+    index maps stop at the last valid block, so only
+    ``ceil(valid / decode_block_kv(...))`` blocks of the layer's panel are
+    DMA'd — the block size the kernel itself takes from the cache's shape.
     ``paged=True`` is the block-table path: ``ceil(valid / block_size)``
     pool blocks of KV rows per layer, plus the scalar-prefetch metadata
     the kernel stages through SMEM (the int32 block-table row and the
-    int32 ``n_valid`` scalar) — with scalar prefetch the index map picks
-    physical blocks before the DMA fires, so unlike the contiguous TPU
-    kernel there is no full-panel delivery to discount (see
-    kernels/README.md).  Analytic — no allocation;
-    ``benchmarks/decode_attn_bench.py`` reports it next to the measured
-    step latency.
+    int32 ``n_valid`` scalar; see kernels/README.md).  Analytic — no
+    allocation; ``benchmarks/decode_attn_bench.py`` reports it next to the
+    measured step latency.
     """
     import math
-
-    from repro.kernels.decode_attention import decode_block_kv
 
     row_bytes = _attn_row_bytes(cfg)
     total = 0
@@ -304,7 +314,7 @@ def decode_read_bytes(
             total += rows * row_bytes + 4 * j_l + 4     # + bt row + n_valid
             continue
         if masked:
-            bkv = decode_block_kv(length, cfg.attn_decode_block_kv)
+            bkv = _decode_block(cfg, length)
             rows = min(math.ceil(min(valid, length) / bkv) * bkv, length)
         else:
             rows = length
@@ -327,8 +337,6 @@ def decode_read_bytes_jnp(
     every concrete ``valid`` (tested) — the per-layer cache lengths and
     effective block sizes are static, only the ceil-to-block arithmetic
     runs on device."""
-    from repro.kernels.decode_attention import decode_block_kv
-
     row_bytes = _attn_row_bytes(cfg)
     valid = jnp.asarray(valid, jnp.float32)
     total = jnp.zeros_like(valid)
@@ -345,7 +353,7 @@ def decode_read_bytes_jnp(
             )
             continue
         if masked:
-            bkv = decode_block_kv(length, cfg.attn_decode_block_kv)
+            bkv = _decode_block(cfg, length)
             v = jnp.minimum(valid, float(length))
             rows = jnp.minimum(jnp.ceil(v / bkv) * bkv, float(length))
         else:

@@ -182,6 +182,154 @@ class TestSlotVmap:
             )
 
 
+def _stacked_pool(seed, slots, units, c, kvh, hd, quantized):
+    """A slot pool's stacked cache of one layer, ``(S, U, 1, C, KV*hd)``
+    rows (plus ``(S, U, 1, C, KV)`` scales when int8), as the engine
+    carries it."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    lead = (slots, units, 1, c)
+    if quantized:
+        return {
+            "k": jax.random.randint(ks[0], lead + (kvh * hd,), -127, 128,
+                                    jnp.int8),
+            "v": jax.random.randint(ks[1], lead + (kvh * hd,), -127, 128,
+                                    jnp.int8),
+            "k_scale": (jax.random.uniform(ks[2], lead + (kvh,)) * 0.05
+                        + 0.01).astype(jnp.bfloat16),
+            "v_scale": (jax.random.uniform(ks[3], lead + (kvh,)) * 0.05
+                        + 0.01).astype(jnp.bfloat16),
+        }
+    return {
+        "k": jax.random.normal(ks[0], lead + (kvh * hd,), jnp.bfloat16),
+        "v": jax.random.normal(ks[1], lead + (kvh * hd,), jnp.bfloat16),
+    }
+
+
+def _unit_layer(pool, unit):
+    """Slot ``s``'s layer cache at ``unit``, ``(S, C, KV*hd)``."""
+    return {name: a[:, unit, 0] for name, a in pool.items()}
+
+
+def _slot_step(impl, block_kv=BKV):
+    """The engine's form: a batch-1 call on one slot's stacked cache,
+    vmapped over the slots with a per-slot ``n_valid``."""
+    def one(q, cache, n, unit):
+        return decode_attention(
+            q[None], cache, n, unit=unit, block_kv=block_kv, impl=impl,
+            interpret=True,
+        )[0]
+
+    return jax.vmap(one, in_axes=(0, 0, 0, None))
+
+
+class TestStackedPool:
+    """The kernel reads unit ``unit`` of the stacked slot pool in place and
+    walks only each slot's valid blocks; one ``pallas_call`` under vmap."""
+
+    SLOTS, UNITS, C, KVH, G, HD = 2, 3, 32, 2, 2, 16
+
+    @pytest.mark.parametrize("unit", [0, 1, 2])
+    @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("n_valid", [1, BKV, BKV + 1, 32])
+    def test_every_unit_equals_ref(self, unit, quantized, n_valid):
+        s, u, c, kvh, g, hd = (self.SLOTS, self.UNITS, self.C, self.KVH,
+                               self.G, self.HD)
+        pool = _stacked_pool(40 + unit, s, u, c, kvh, hd, quantized)
+        q = jax.random.normal(jax.random.PRNGKey(unit), (s, 1, kvh, g, hd),
+                              jnp.bfloat16)
+        n = jnp.full((s,), n_valid, jnp.int32)
+        got = _slot_step("kernel")(q, pool, n, jnp.int32(unit))
+        layer = _unit_layer(pool, unit)
+        want = decode_attention(q, layer, n, block_kv=BKV, impl="ref")
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=1e-2, atol=1e-2,
+        )
+        oracle = _oracle(q.astype(jnp.float32),
+                         {k: a.reshape(a.shape[:2] + (kvh, -1))
+                          if k in ("k", "v") else a
+                          for k, a in layer.items()}, n[:, None])
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(oracle, np.float32),
+            rtol=2e-2, atol=2e-2,
+        )
+
+    @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+    def test_vmap_per_slot_n_valid(self, quantized):
+        """Slots of different lengths in one call, each against its own
+        batch-1 reference."""
+        s, u, c, kvh, g, hd = 4, 2, self.C, self.KVH, self.G, self.HD
+        pool = _stacked_pool(50, s, u, c, kvh, hd, quantized)
+        q = jax.random.normal(jax.random.PRNGKey(5), (s, 1, kvh, g, hd))
+        n = jnp.array([1, BKV, BKV + 1, c], jnp.int32)
+        got = _slot_step("kernel")(q, pool, n, jnp.int32(1))
+        layer = _unit_layer(pool, 1)
+        for i in range(s):
+            want = decode_attention(
+                q[i : i + 1], {k: a[i : i + 1] for k, a in layer.items()},
+                n[i], block_kv=BKV, impl="ref",
+            )
+            np.testing.assert_allclose(
+                np.asarray(got[i], np.float32),
+                np.asarray(want[0], np.float32), rtol=2e-6, atol=2e-6,
+                err_msg=f"slot {i}, n_valid {int(n[i])}",
+            )
+
+    @pytest.mark.parametrize("impl", ["kernel", "ref"])
+    def test_windowed_cache_across_wrap(self, impl):
+        """A rotating window of C rows written past its wrap: the live rows
+        stay the prefix ``[0, min(index + 1, C))`` and each step attends
+        over exactly the last C positions written."""
+        u, c, kvh, g, hd = 2, 16, self.KVH, self.G, self.HD
+        rng = jax.random.split(jax.random.PRNGKey(60), 3)
+        rows_k = jax.random.normal(rng[0], (24, kvh * hd))
+        rows_v = jax.random.normal(rng[1], (24, kvh * hd))
+        q = jax.random.normal(rng[2], (1, 1, kvh, g, hd))
+        pool = {"k": jnp.zeros((1, u, 1, c, kvh * hd)),
+                "v": jnp.zeros((1, u, 1, c, kvh * hd))}
+        for index in range(24):
+            for name, rows in (("k", rows_k), ("v", rows_v)):
+                pool[name] = pool[name].at[0, 1, 0, index % c].set(rows[index])
+            n = jnp.minimum(jnp.int32(index) + 1, c)[None]
+            got = _slot_step(impl, block_kv=4)(q, pool, n, jnp.int32(1))
+            live = jnp.arange(max(0, index + 1 - c), index + 1)
+            heads = lambda a: a[live].reshape(1, -1, kvh, hd)
+            want = _oracle(q, {"k": heads(rows_k), "v": heads(rows_v)},
+                           jnp.int32(live.shape[0]))
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5,
+                err_msg=f"index {index}",
+            )
+
+    def test_vmapped_call_is_one_pallas_call(self):
+        """vmapped over the slots, the walk binds one ``pallas_call`` with
+        the slots as its request axis, and no loop runs around it (the
+        per-slot fallback ``pallas_call`` takes for batched scalar
+        prefetch is a loop of calls)."""
+        s, u, c, kvh, g, hd = 4, 3, self.C, self.KVH, self.G, self.HD
+        pool = _stacked_pool(70, s, u, c, kvh, hd, True)
+        q = jnp.zeros((s, 1, kvh, g, hd), jnp.bfloat16)
+        n = jnp.arange(1, s + 1, dtype=jnp.int32)
+        jaxpr = jax.make_jaxpr(_slot_step("kernel"))(q, pool, n, jnp.int32(2))
+
+        def prims(jx):
+            for eqn in jx.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from prims(sub)
+
+        eqns = list(prims(jaxpr.jaxpr))
+        calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        assert not [e.primitive.name for e in eqns
+                    if e.primitive.name in ("while", "scan")]
+        # the request axis is every slot; K/V are the whole pool
+        assert calls[0].outvars[0].aval.shape[0] == s
+        pool_rows = {tuple(v.aval.shape) for v in calls[0].invars}
+        assert (s * u, c, kvh * hd) in pool_rows
+
+
 class TestRotatingWindowIntegration:
     def test_windowed_decode_across_wrap(self):
         """Sliding-window layer stepped past the wrap point: flash_decode
@@ -191,7 +339,7 @@ class TestRotatingWindowIntegration:
         cfg_n = ARCHITECTURES["gemma3-12b"].reduced()
         pat = tuple(dataclasses.replace(s, window=8) if s.window else s
                     for s in cfg_n.unit_pattern)
-        cfg_n = cfg_n.with_updates(unit_pattern=pat, attn_decode_block_kv=4)
+        cfg_n = cfg_n.with_updates(unit_pattern=pat)
         cfg_f = cfg_n.with_updates(attn_impl="flash_decode")
         params = lm.init_lm(jax.random.PRNGKey(0), cfg_n)
         caches = {
@@ -371,16 +519,23 @@ class TestContinuousEngineFlashDecode:
 
 class TestHelpers:
     def test_decode_block_kv_divides_or_pads(self):
-        assert decode_block_kv(1024, 64) == 64
-        assert decode_block_kv(32, 64) == 32
-        assert decode_block_kv(192, 64) == 64
-        assert decode_block_kv(7, 64) == 7       # single block, no pad
+        """The block comes from the cache's shape: about 128 KB of K rows,
+        a divisor of the length in whole packed tiles, else the aim (the
+        op pads)."""
+        assert decode_block_kv(320, 1024, 2) == 64    # qwen1.5-0.5b, bf16
+        assert decode_block_kv(768, 512, 2) == 128    # codeqwen1.5-7b, bf16
+        assert decode_block_kv(320, 1024, 1) == 64    # int8: aim 128
+        assert decode_block_kv(7, 1024, 2) == 7       # single block, no pad
+        assert decode_block_kv(1024, 32, 4) == 1024   # tiny rows: one block
         # Coprime-ish lengths keep a real block (ops pads the cache)
-        # instead of collapsing to gcd-sized micro-blocks.
-        assert decode_block_kv(100, 64) == 64
-        assert decode_block_kv(65, 64) == 64
-        for c, b in [(1024, 64), (192, 64), (24, 64), (7, 64), (48, 32)]:
-            assert c % decode_block_kv(c, b) == 0
+        # instead of collapsing to micro-blocks.
+        assert decode_block_kv(1000, 1024, 2) == 64
+        assert decode_block_kv(4100, 512, 2) == 128
+        for c, lanes, size in [(320, 1024, 2), (768, 512, 2), (192, 1024, 1),
+                               (4096, 256, 2), (48, 4096, 4), (24, 32, 4)]:
+            bkv = decode_block_kv(c, lanes, size)
+            assert c % bkv == 0
+            assert bkv == c or bkv % (32 // size) == 0
 
     @pytest.mark.parametrize("impl", ["ref", "kernel"])
     def test_degenerate_cache_length_pads_correctly(self, impl):

@@ -205,7 +205,10 @@ def test_decode_step_copies_no_stacked_segment(one_chip, monkeypatch):
     the link after the first, compiled for a v5e: no concatenate, slice
     or copy shaped like two or more units of the slot pool or of the
     stacked weight matrices (a relayout of the pool, or a segment cut at
-    the split), and the pool's K/V alias the donated input."""
+    the split); no copy, dynamic-slice or fusion shaped like one unit's
+    panel of the pool (the layer's cache cut out for the kernel); the
+    decode kernel's K/V operands are the whole pool, one call per layer;
+    and the pool's K/V alias the donated input."""
     import dataclasses
 
     from repro.configs import get_config
@@ -235,6 +238,21 @@ def test_decode_step_copies_no_stacked_segment(one_chip, monkeypatch):
     ops = re.findall(r"\[([\d,]*)\]\{[^}]*\} (concatenate|slice|copy)\(", text)
     for dims, op in ops:
         assert tuple(int(d) for d in dims.split(",") if d) not in banned, (op, dims)
+    pool = state["cache"]["units"][0]["k"]               # (S, U, 1, C, KV*hd)
+    slots, units, _, c, lanes = pool.shape
+    panel = "{},1,{},{}".format(slots, c, lanes)
+    ops = re.findall(r"\[([\d,]*)\]\{[^}]*\} (copy|dynamic-slice|fusion)\(",
+                     text)
+    assert not [op for dims, op in ops
+                if dims in (panel, "{},{},{}".format(slots, c, lanes))]
+    calls = [line for line in text.splitlines()
+             if re.search(r"%flash_decode_kernel[.\d]* = .*custom-call\(", line)]
+    # one call in each half's loop over its units: one call per layer
+    assert len(calls) == 2
+    whole = "bf16[{},{},{}]".format(slots * units, c, lanes)
+    for line in calls:
+        operands = line.split("operand_layout_constraints=")[1]
+        assert operands.split("metadata=")[0].count(whole) == 2, line[:300]
     n_params = len(jax.tree_util.tree_leaves(params))
     aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", text))
     leaves = jax.tree_util.tree_leaves_with_path(state)
